@@ -37,10 +37,12 @@ print(f"RRFP (BF hint):     {r_rrfp.makespan:.3f}s  "
       f"speedup {r_fixed.makespan / r_rrfp.makespan:.2f}x")
 
 print("\n== compiled executor: train a tiny LM with the RRFP table ==")
+from repro.configs import registry
 from repro.launch.train import build_trainer
 from repro.data.synthetic import synth_batch
 
-t = build_trainer("deepseek-7b", data=2, stages=4, layers=8, mb_rows=1,
+t = build_trainer(registry.reduced_config("deepseek-7b", num_layers=8),
+                  data=2, stages=4, mb_rows=1,
                   microbatches=8, seq=64, schedule="rrfp")
 sp, io, opt = t["stage_params"], t["io_params"], t["opt_state"]
 for step in range(5):

@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+import math
 
 import jax.numpy as jnp
 
@@ -35,6 +36,55 @@ def get_arch(name: str) -> ArchConfig:
 
 def list_archs() -> tuple[str, ...]:
     return ARCHS
+
+
+def depth_period(cfg: ArchConfig) -> int:
+    """Layers in one repeat of ``cfg``'s layer structure: a depth cut keeps
+    whole repeats, so every layer kind keeps its published share."""
+    period = 1
+    if cfg.layer_pattern is not None:
+        pat = cfg.layer_pattern
+        period = next(p for p in range(1, len(pat) + 1)
+                      if all(t == pat[i % p] for i, t in enumerate(pat)))
+    if cfg.local_global_period:
+        period = math.lcm(period, cfg.local_global_period)
+    if cfg.shared_attn_period:
+        period = math.lcm(period, cfg.shared_attn_period)
+    if cfg.encoder_layers:
+        period = math.lcm(period, cfg.num_layers
+                          // math.gcd(cfg.encoder_layers, cfg.num_layers))
+    return period
+
+
+def depth_cut(name: str, num_layers: int | None = None) -> ArchConfig:
+    """The published config at every published width, cut in depth only.
+
+    ``num_layers`` must be a whole number of :func:`depth_period` repeats;
+    None keeps the published depth.
+    """
+    cfg = get_arch(name)
+    if num_layers is None or num_layers == cfg.num_layers:
+        return cfg
+    period = depth_period(cfg)
+    if not 0 < num_layers <= cfg.num_layers or num_layers % period:
+        raise ValueError(
+            f"{name}: cannot cut {cfg.num_layers} layers to {num_layers}; a "
+            f"depth cut keeps whole {period}-layer periods of the pattern")
+    upd: dict = dict(num_layers=num_layers)
+    if cfg.layer_pattern is not None:
+        upd["layer_pattern"] = cfg.layer_pattern[:num_layers]
+    if cfg.encoder_layers:
+        upd["encoder_layers"] = (cfg.encoder_layers * num_layers
+                                 // cfg.num_layers)
+    return dataclasses.replace(cfg, **upd)
+
+
+def model_config(name: str, num_layers: int | None = None, *,
+                 full_size: bool = False) -> ArchConfig:
+    """``--full-size``: :func:`depth_cut`; otherwise :func:`reduced_config`."""
+    if full_size:
+        return depth_cut(name, num_layers)
+    return reduced_config(name, num_layers)
 
 
 def reduced_config(name: str, num_layers: int | None = None) -> ArchConfig:

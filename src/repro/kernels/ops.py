@@ -1,13 +1,13 @@
-"""Public kernel API: jit'd wrappers dispatching XLA <-> Pallas backends.
+"""Public kernel API: one entry per op, the implementation chosen by platform.
 
-Backends:
-  "xla"       — pure-jnp blocked implementations (differentiable, compiles on
-                any backend; the multi-pod dry-run uses this path).
-  "pallas"    — the TPU kernels (pl.pallas_call), forward custom-vjp'd onto
-                the XLA backward (recompute), TPU-only.
-  "interpret" — the Pallas kernels executed by the interpreter (CPU tests).
+A program lowered for a TPU runs the Pallas kernels (``pl.pallas_call``,
+forward custom-vjp'd onto an XLA recompute backward); on every other platform
+it runs the pure-jnp blocked implementations.  The choice is made when the
+computation is lowered (``jax.lax.platform_dependent``), so a program compiled
+from a CPU process for a described TPU topology takes the kernels too.
 
-Select globally with ``set_backend`` or per-call with ``backend=``.
+``backend=`` pins one side for tests: ``"xla"`` the jnp path, ``"interpret"``
+the Pallas kernels under the interpreter (any platform).
 """
 from __future__ import annotations
 
@@ -23,22 +23,17 @@ from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import ref as _ref
 
-Backend = Literal["xla", "pallas", "interpret"]
-_BACKEND: Backend = "xla"
+Backend = Literal["xla", "interpret"]
 
 
-def set_backend(b: Backend) -> None:
-    global _BACKEND
-    assert b in ("xla", "pallas", "interpret"), b
-    _BACKEND = b
-
-
-def get_backend() -> Backend:
-    return _BACKEND
-
-
-def _resolve(backend: Backend | None) -> Backend:
-    return backend or _BACKEND
+def _dispatch(backend: Backend | None, kernel, xla, *args):
+    """``kernel(*args)`` on TPU, ``xla(*args)`` elsewhere, unless pinned."""
+    if backend == "xla":
+        return xla(*args)
+    if backend == "interpret":
+        return kernel(*args, interpret=True)
+    assert backend is None, backend
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=xla)
 
 
 # ---------------------------------------------------------------------------
@@ -47,19 +42,23 @@ def _resolve(backend: Backend | None) -> Backend:
 def flash_attention(q, k, v, positions, *, causal: bool = True, window: int = 0,
                     backend: Backend | None = None):
     """q: [b, sq, hq, hd]; k, v: [b, sk, hkv, hd]; positions: [b, sq]."""
-    be = _resolve(backend)
-    if be == "xla":
+
+    def xla(q, k, v, positions):
         from repro.models.layers import blocked_attention
 
         return blocked_attention(q, k, v, positions, causal, window, 256)
-    # Pallas path assumes training self-attention: positions == arange(sq).
-    hd = q.shape[-1]
-    qt = jnp.swapaxes(q, 1, 2) * (hd ** -0.5)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = _pallas_attention(qt.astype(q.dtype), kt, vt, causal, window,
-                            be == "interpret")
-    return jnp.swapaxes(out, 1, 2)
+
+    def kernel(q, k, v, positions, interpret=False):
+        # Pallas path assumes training self-attention: positions == arange(sq).
+        hd = q.shape[-1]
+        qt = jnp.swapaxes(q, 1, 2) * (hd ** -0.5)
+        kt = jnp.swapaxes(k, 1, 2)
+        vt = jnp.swapaxes(v, 1, 2)
+        out = _pallas_attention(qt.astype(q.dtype), kt, vt, causal, window,
+                                interpret)
+        return jnp.swapaxes(out, 1, 2)
+
+    return _dispatch(backend, kernel, xla, q, k, v, positions)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -93,18 +92,22 @@ _pallas_attention.defvjp(_pallas_attention_fwd, _pallas_attention_bwd)
 def decode_attention(q, k_cache, v_cache, length, *, window: int = 0,
                      backend: Backend | None = None):
     """q: [b, 1, hq, hd]; caches: [b, S, hkv, hd]; length: scalar int."""
-    be = _resolve(backend)
-    b = q.shape[0]
-    if be == "xla":
-        lengths = jnp.full((b,), length, jnp.int32)
+
+    def xla(q, k_cache, v_cache, length):
+        lengths = jnp.full((q.shape[0],), length, jnp.int32)
         return _ref.decode_ref(q, k_cache, v_cache, lengths, window=window)
-    hd = q.shape[-1]
-    qt = jnp.swapaxes(q, 1, 2) * (hd ** -0.5)
-    out = _fd.flash_decode(
-        qt.astype(q.dtype), jnp.swapaxes(k_cache, 1, 2),
-        jnp.swapaxes(v_cache, 1, 2), length, window=window,
-        interpret=be == "interpret")
-    return jnp.swapaxes(out, 1, 2)
+
+    def kernel(q, k_cache, v_cache, length, interpret=False):
+        hd = q.shape[-1]
+        qt = jnp.swapaxes(q, 1, 2) * (hd ** -0.5)
+        out = _fd.flash_decode(
+            qt.astype(q.dtype), jnp.swapaxes(k_cache, 1, 2),
+            jnp.swapaxes(v_cache, 1, 2), length, window=window,
+            interpret=interpret)
+        return jnp.swapaxes(out, 1, 2)
+
+    return _dispatch(backend, kernel, xla, q, k_cache, v_cache,
+                     jnp.asarray(length, jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -112,23 +115,30 @@ def decode_attention(q, k_cache, v_cache, length, *, window: int = 0,
 # ---------------------------------------------------------------------------
 def ssd(x, dt, A, B, C, D, *, chunk: int = 128, backend: Backend | None = None):
     """x: [b, s, nh, hd]; dt: [b, s, nh]; A, D: [nh]; B, C: [b, s, ds]."""
-    be = _resolve(backend)
-    if be == "xla":
+
+    def xla(x, dt, A, B, C, D):
         return _ssd_xla_chunked(x, dt, A, B, C, D, chunk)
-    s = x.shape[1]
-    pad = (-s) % chunk
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
-        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
-    y = _pallas_ssd(x, dt, A, B, C, D, chunk, be == "interpret")
-    return y[:, :s]
+
+    def kernel(x, dt, A, B, C, D, interpret=False):
+        s = x.shape[1]
+        pad = (-s) % chunk
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+            B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
+            C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
+        y = _pallas_ssd(x, dt, A, B, C, D, chunk, interpret)
+        return y[:, :s]
+
+    return _dispatch(backend, kernel, xla, x, dt, A, B, C, D)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _pallas_ssd(x, dt, A, B, C, D, chunk, interpret):
-    return _ssd.ssd_scan(x, dt, A, B, C, D, chunk=chunk, interpret=interpret)
+    # the kernel is head-major; the model is sequence-major
+    y = _ssd.ssd_scan(jnp.swapaxes(x, 1, 2), dt, A, B, C, D, chunk=chunk,
+                      interpret=interpret)
+    return jnp.swapaxes(y, 1, 2)
 
 
 def _pallas_ssd_fwd(x, dt, A, B, C, D, chunk, interpret):
@@ -226,7 +236,11 @@ def ssd_decode_step(state, x, dt, A, B, C, D):
 # RMSNorm
 # ---------------------------------------------------------------------------
 def rmsnorm(x, scale, *, eps: float = 1e-5, backend: Backend | None = None):
-    be = _resolve(backend)
-    if be == "xla":
+
+    def xla(x, scale):
         return _ref.rmsnorm_ref(x, scale, eps)
-    return _rn.rmsnorm(x, scale, eps=eps, interpret=be == "interpret")
+
+    def kernel(x, scale, interpret=False):
+        return _rn.rmsnorm(x, scale, eps=eps, interpret=interpret)
+
+    return _dispatch(backend, kernel, xla, x, scale)

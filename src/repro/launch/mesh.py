@@ -5,7 +5,12 @@ importing this module touches no jax device state.
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh as _make_mesh
+import jax
+
+
+def _make_mesh(shape, axes):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,8 +21,7 @@ from repro.pipeline.sharding import partition_for
 
 def build_server(arch: str, *, data: int, stages: int, layers: int | None,
                  batch: int, cache_len: int, reduced: bool = True):
-    cfg = (registry.reduced_config(arch, num_layers=layers)
-           if reduced else registry.get_arch(arch))
+    cfg = registry.model_config(arch, layers, full_size=not reduced)
     model = build(cfg, num_stages=stages)
     mesh = make_mesh(data, stages)
     key = jax.random.key(0)

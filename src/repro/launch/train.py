@@ -21,12 +21,14 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import os
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.ckpt.store import CheckpointStore
 from repro.configs import registry
@@ -34,8 +36,10 @@ from repro.core.costs import CostModel
 from repro.core.hints import HintKind
 from repro.core.taskgraph import PipelineSpec
 from repro.data.synthetic import PrefetchIterator, synth_batch
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.build import build
+from repro.models.common import ArchConfig
 from repro.optim.adamw import AdamWConfig, make_optimizer
 from repro.pipeline import schedules
 from repro.pipeline.executor import ExecOptions, make_train_fn
@@ -43,18 +47,52 @@ from repro.pipeline.sharding import partition_for
 from repro.runtime.straggler import StragglerMonitor
 
 
-def build_trainer(arch: str, *, data: int, stages: int, layers: int | None,
+@dataclasses.dataclass
+class RunLog:
+    """What a language-workload run returns: per step, the loss and the host
+    seconds the step took, ending once its results are on the host."""
+    losses: list[float] = dataclasses.field(default_factory=list)
+    seconds: list[float] = dataclasses.field(default_factory=list)
+
+
+def model_config(args) -> ArchConfig:
+    """The run's config (``--full-size``: published widths, cut in depth
+    only), announced on one line."""
+    cfg = registry.model_config(args.arch, args.layers,
+                                full_size=args.full_size)
+    print(f"arch={args.arch} "
+          f"{'published' if args.full_size else 'reduced'} widths "
+          f"d_model={cfg.d_model} heads={cfg.num_heads} d_ff={cfg.d_ff} "
+          f"vocab={cfg.vocab_size}  depth {cfg.num_layers} of "
+          f"{registry.get_arch(args.arch).num_layers}", flush=True)
+    return cfg
+
+
+def init_params(model):
+    """Initial (stage, io) parameters from one fixed key: every runtime and
+    every stage split of a config starts from the same model."""
+    key = jax.random.key(0)
+    return (model.init_stage_params(key),
+            model.init_io_params(jax.random.fold_in(key, 1)))
+
+
+def build_trainer(cfg: ArchConfig, *, data: int, stages: int,
                   mb_rows: int, microbatches: int, seq: int,
-                  schedule: str = "rrfp", reduced: bool = True,
+                  schedule: str = "rrfp",
                   lr: float = 1e-3, total_steps: int = 1000):
-    cfg = (registry.reduced_config(arch, num_layers=layers)
-           if reduced else registry.get_arch(arch))
     model = build(cfg, num_stages=stages)
     mesh = make_mesh(data, stages)
-    key = jax.random.key(0)
-    stage_params = model.init_stage_params(key)
-    io_params = model.init_io_params(jax.random.fold_in(key, 1))
+    stage_params, io_params = init_params(model)
     partition = partition_for(model, stage_params, io_params)
+    # place the parameters where train_step returns them: step 1 then reuses
+    # step 0's compiled program instead of compiling it again
+    def on_mesh(tree, specs):
+        return jax.device_put(tree, jax.tree.map(
+            lambda s: NamedSharding(mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, PartitionSpec)))
+
+    stage_params = on_mesh(stage_params, partition.stage_specs)
+    io_params = on_mesh(io_params, partition.io_specs)
 
     spec = PipelineSpec(stages, microbatches,
                         split_backward=(schedule == "zb"))
@@ -66,7 +104,7 @@ def build_trainer(arch: str, *, data: int, stages: int, layers: int | None,
     opt_cfg = AdamWConfig(lr=lr, warmup_steps=20, total_steps=total_steps)
     opt_init, opt_update = make_optimizer(model, mesh, partition, opt_cfg)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
     def train_step(stage_params, io_params, opt_state, batch, step):
         metrics, grad_shard, expert_grads = exec_fn(
             stage_params, io_params, batch)
@@ -307,23 +345,18 @@ def train_multimodal(args) -> list[float]:
 # ---------------------------------------------------------------------------
 # actor-runtime backend (opt-in via --runtime actor)
 # ---------------------------------------------------------------------------
-def train_actor(args) -> list[float]:
+def train_actor(args, cfg: ArchConfig) -> RunLog:
     """Train with thread-per-stage actors dispatching real stage callables.
 
     Single-process: stage s's parameters live with stage s's actor; AdamW
-    runs host-side over the accumulated per-stage grads.  Returns the loss
-    history (for tests)."""
+    runs host-side over the accumulated per-stage grads."""
     from repro.optim.adamw import make_host_update
     from repro.pipeline.stagefn import (
-        ActorStageProgram, StageFnOptions, StageFns)
+        ActorStageProgram, StageFnOptions, StageFns, warm_up)
     from repro.runtime.rrfp import ActorConfig, ActorDriver, Trace, parse_chaos
 
-    cfg = (registry.reduced_config(args.arch, num_layers=args.layers)
-           if not args.full_size else registry.get_arch(args.arch))
     model = build(cfg, num_stages=args.stages)
-    key = jax.random.key(0)
-    stage_params = model.init_stage_params(key)
-    io_params = model.init_io_params(jax.random.fold_in(key, 1))
+    stage_params, io_params = init_params(model)
     split = args.split_backward or args.schedule == "zb"
     hint = HintKind(args.hint)
     chaos = parse_chaos(args.chaos) if args.chaos else None
@@ -427,12 +460,20 @@ def train_actor(args) -> list[float]:
     print(f"arch={args.arch} N={cfg.param_count():,} params  runtime=actor "
           f"mode={mode}  hint={hint.value}  split_backward={split}  "
           f"stages={args.stages}  microbatches={args.microbatches}")
-    losses: list[float] = []
+    log = RunLog()
     obs_trace = None
     for step in range(start_step, args.steps):
+        t0 = time.time()
         batch = synth_batch(cfg, batch_size, args.seq, seed=args.seed,
                             step=step)
         sp, io = params["sp"], params["io"]
+        if step == start_step:
+            # compile every stage callable before the threads start: a cold
+            # full-width compile outlasts the starvation deadline otherwise
+            warm_up(fns, [jax.tree.map(lambda x, s=s: x[s], sp)
+                          for s in range(args.stages)], io, batch,
+                    split_backward=split)
+            print(f"warm-up (compile) {time.time() - t0:.1f} s", flush=True)
         programs = [
             ActorStageProgram(
                 fns, s, jax.tree.map(lambda x, s=s: x[s], sp), io, batch,
@@ -459,7 +500,6 @@ def train_actor(args) -> list[float]:
                 split_backward=split)
             return programs[s]
 
-        t0 = time.time()
         # recording costs lock traffic on the dispatch path: enable it only
         # for the step whose trace is actually saved
         record_this = _obs_record_step0(args, step, first=start_step)
@@ -487,7 +527,9 @@ def train_actor(args) -> list[float]:
         # single device sync per step: the programs accumulate the loss as a
         # device array (no float() in the F hot path)
         loss = float(sum(p.loss_acc for p in programs)) / tokens
-        losses.append(loss)
+        jax.block_until_ready(params)
+        log.losses.append(loss)
+        log.seconds.append(time.time() - t0)
         if record_this:
             trace = driver.trace
             trace.meta["step"] = step
@@ -505,12 +547,12 @@ def train_actor(args) -> list[float]:
             if decision.swapped:
                 swap_note = (f"  [hint-swap v{scheduler.version} "
                              f"ratio={decision.ratio:.3f}]")
-        dt = time.time() - t0
         print(f"step {step:4d}  loss {loss:8.4f}  lr {float(lr):.2e}  "
-              f"{dt*1e3:7.1f} ms  makespan {result.makespan*1e3:7.1f} ms  "
+              f"{log.seconds[-1]*1e3:7.1f} ms  "
+              f"makespan {result.makespan*1e3:7.1f} ms  "
               f"blocking {bd['blocking']*1e3:6.1f} ms"
               + ("  [replan]" if new_table is not None else "")
-              + swap_note)
+              + swap_note, flush=True)
         if store and (step + 1) % args.ckpt_every == 0:
             store.save(step + 1,
                        {"params": params, "m": mstate, "v": vstate},
@@ -522,17 +564,24 @@ def train_actor(args) -> list[float]:
               f"{len(scheduler.swaps)} time(s) at step(s) {scheduler.swaps} "
               f"(table v{scheduler.version})")
     _obs_finish(args, metrics_reg, obs_trace)
-    return losses
+    return log
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> RunLog | None:
+    """Parse ``argv`` (default: the command line) and train.  Returns the
+    :class:`RunLog` of a language-workload run."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="architecture id (default: deepseek-7b, or "
                          "qwen2-vl-2b for --workload multimodal)")
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="devices in the compiled executor's mesh "
+                         "(default: every device JAX finds)")
     ap.add_argument("--stages", type=int, default=4)
-    ap.add_argument("--layers", type=int, default=8)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth (default: 8 reduced; the published depth "
+                         "under --full-size, where a cut keeps whole periods "
+                         "of the layer pattern)")
     ap.add_argument("--mb-rows", type=int, default=1)
     ap.add_argument("--microbatches", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -616,7 +665,9 @@ def main() -> None:
     ap.add_argument("--hb-deadline", type=float, default=2.0,
                     help="actor runtime, --recover: seconds without stage "
                          "progress before a permanent stall is declared dead")
-    ap.add_argument("--full-size", action="store_true")
+    ap.add_argument("--full-size", action="store_true",
+                    help="published widths (default: a reduced toy width "
+                         "for CPU runs)")
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=None,
@@ -625,9 +676,19 @@ def main() -> None:
                          "exactly the params the failed step started from)")
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.ckpt_every is None:
         args.ckpt_every = 1 if args.recover else 10
+    if args.layers is None and not args.full_size:
+        args.layers = 8
+    available = jax.device_count()
+    if args.devices is None:
+        args.devices = available
+    if args.devices > available:
+        raise SystemExit(
+            f"--devices {args.devices}: JAX finds only {available} "
+            f"{jax.devices()[0].platform} device(s)")
+    use_compile_cache()
 
     if args.recover and not (args.runtime == "actor"
                              and args.workload == "language"):
@@ -640,27 +701,29 @@ def main() -> None:
     if args.workload == "multimodal":
         args.runtime = "actor"  # the DAG only runs on the actor runtime
         train_multimodal(args)
-        return
+        return None
     if args.arch is None:
         args.arch = "deepseek-7b"
-    if args.runtime == "actor":
-        train_actor(args)
-        return
-    if args.metrics_report or args.export_perfetto or args.explain:
+    if args.runtime == "table" and (args.metrics_report or args.export_perfetto
+                                    or args.explain):
         raise SystemExit("--metrics-report / --export-perfetto / --explain "
                          "instrument the actor runtime; add --runtime actor "
                          "(or --workload multimodal)")
-
     data = args.devices // args.stages
-    assert data >= 1, "need devices >= stages"
+    if args.runtime == "table" and data < 1:
+        raise SystemExit(f"--stages {args.stages} needs at least as many "
+                         f"devices; --devices is {args.devices}")
+    cfg = model_config(args)
+    if args.runtime == "actor":
+        return train_actor(args, cfg)
+
     t = build_trainer(
-        args.arch, data=data, stages=args.stages, layers=args.layers,
+        cfg, data=data, stages=args.stages,
         mb_rows=args.mb_rows, microbatches=args.microbatches, seq=args.seq,
-        schedule=args.schedule, reduced=not args.full_size, lr=args.lr,
-        total_steps=args.steps)
-    print(f"arch={args.arch} N={t['cfg'].param_count():,} params  "
+        schedule=args.schedule, lr=args.lr, total_steps=args.steps)
+    print(f"arch={args.arch} N={cfg.param_count():,} params  "
           f"mesh=({data}×{args.stages})  schedule={args.schedule}  "
-          f"bubble={t['table'].bubble_fraction():.2f}")
+          f"bubble={t['table'].bubble_fraction():.2f}", flush=True)
 
     store = CheckpointStore(args.ckpt_dir) if args.ckpt_dir else None
     start_step = 0
@@ -684,17 +747,19 @@ def main() -> None:
     it = PrefetchIterator(make, start_step=start_step)
     sp, io, opt = (state["stage_params"], state["io_params"],
                    state["opt_state"])
+    log = RunLog()
     try:
         for _ in range(args.steps - start_step):
             step, batch = next(it)
             t0 = time.time()
             sp, io, opt, m = t["train_step"](
                 sp, io, opt, batch, jnp.asarray(step, jnp.int32))
-            loss = float(m["loss"])
-            dt = time.time() - t0
-            print(f"step {step:4d}  loss {loss:8.4f}  gnorm "
+            jax.block_until_ready((sp, io, opt, m))
+            log.losses.append(float(m["loss"]))
+            log.seconds.append(time.time() - t0)
+            print(f"step {step:4d}  loss {log.losses[-1]:8.4f}  gnorm "
                   f"{float(m['gnorm']):7.3f}  lr {float(m['lr']):.2e}  "
-                  f"{dt*1e3:7.1f} ms")
+                  f"{log.seconds[-1]*1e3:7.1f} ms", flush=True)
             if store and (step + 1) % args.ckpt_every == 0:
                 store.save(step + 1,
                            {"stage_params": sp, "io_params": io,
@@ -705,6 +770,7 @@ def main() -> None:
             store.wait()
     finally:
         it.close()
+    return log
 
 
 if __name__ == "__main__":

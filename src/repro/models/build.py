@@ -113,13 +113,19 @@ class ArchModel:
         return p
 
     def init_stage_params(self, key):
-        """[S, l_max, ...] stacked union params."""
+        """[S, l_max, ...] stacked union params.  A layer's key is its
+        global index, so one key gives the same model under any stage split
+        (disabled slots get keys past the last layer)."""
+        gli = global_layer_index(self.counts)
+        spare = self.cfg.num_layers
         slots = []
         for s in range(self.num_stages):
-            row = [
-                self.init_layer_params(jax.random.fold_in(key, s * 1000 + i))
-                for i in range(self.l_max)
-            ]
+            row = []
+            for i in range(self.l_max):
+                g = int(gli[s, i])
+                if g < 0:
+                    g, spare = spare, spare + 1
+                row.append(self.init_layer_params(jax.random.fold_in(key, g)))
             slots.append(_tree_stack(row))
         return _tree_stack(slots)
 

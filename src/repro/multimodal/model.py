@@ -169,8 +169,7 @@ def multimodal_config(
             f"{arch!r} is not a multimodal arch; available: "
             f"{sorted(MULTIMODAL_ARCHS)}")
     modality = MULTIMODAL_ARCHS[arch]
-    cfg = (registry.reduced_config(arch, num_layers=num_layers)
-           if reduced else registry.get_arch(arch))
+    cfg = registry.model_config(arch, num_layers, full_size=not reduced)
     # encoder width: half the LM width (rounded to a head multiple) — cheap
     # per-token relative to the decoder, like a ViT/conformer frontend
     enc_heads = max(1, cfg.num_heads // 2)

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.pipeline.sharding import ParamPartition
 
 
@@ -157,7 +156,7 @@ def make_optimizer(model, mesh, partition: ParamPartition, opt_cfg: AdamWConfig,
         "experts": {k: {"m": s, "v": s} for k, s in expert_specs.items()},
     }
 
-    init_fn = shard_map(
+    init_fn = jax.shard_map(
         device_init, mesh=mesh,
         in_specs=(partition.stage_specs, partition.io_specs),
         out_specs=state_specs, check_vma=False)
@@ -227,7 +226,7 @@ def make_optimizer(model, mesh, partition: ParamPartition, opt_cfg: AdamWConfig,
                 stats)
 
     grad_specs = {k: shard_spec for k in shard_keys}
-    update_fn = shard_map(
+    update_fn = jax.shard_map(
         device_update, mesh=mesh,
         in_specs=(partition.stage_specs, partition.io_specs, state_specs,
                   grad_specs, expert_specs, P()),

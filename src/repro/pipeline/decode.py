@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.build import ArchModel
 from repro.models.layers import rmsnorm
 from repro.pipeline.spec import OP_F, ScheduleTable
@@ -170,7 +169,7 @@ def make_serve_fn(model: ArchModel, mesh, opts: DecodeOptions, num_groups: int):
     from repro.pipeline.sharding import partition_for  # specs only
 
     def wrap(partition):
-        return shard_map(
+        return jax.shard_map(
             device_fn,
             mesh=mesh,
             in_specs=(partition.stage_specs, partition.io_specs, cspecs,

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.build import ArchModel
 from repro.pipeline.sharding import ParamPartition, partition_for
 from repro.pipeline.spec import OP_B, OP_F, OP_IDLE, OP_W, ScheduleTable
@@ -334,7 +333,7 @@ def make_train_fn(
         if flag
     }
 
-    fn = shard_map(
+    fn = jax.shard_map(
         device_fn,
         mesh=mesh,
         in_specs=(partition.stage_specs, partition.io_specs, batch_specs),

@@ -354,3 +354,27 @@ class ActorStageProgram:
             self._add_grads(task.mb, dsp, dio)
             return None  # stage-local: no outgoing envelope
         raise ValueError(f"actor stage program cannot run {task!r}")
+
+
+def warm_up(fns: StageFns, stage_params: list, io, batch: dict, *,
+            split_backward: bool = False) -> None:
+    """Compile every stage's callables before threads dispatch them.
+
+    Runs microbatch 0 through throwaway programs in pipeline order: F down
+    the chain, then B (and W under split backward) back up — the same calls,
+    with the same shapes, that the first threaded step makes.  A cold compile
+    at full width can outlast the actor runtime's starvation deadline, which
+    would then report a deadlock that is only a compilation.
+    """
+    progs = [ActorStageProgram(fns, s, sp_s, io, batch,
+                               split_backward=split_backward)
+             for s, sp_s in enumerate(stage_params)]
+    payload = None
+    for s, prog in enumerate(progs):
+        payload = prog(Task(Kind.F, s, 0), payload)
+    payload = None
+    for s in reversed(range(len(progs))):
+        payload = progs[s](Task(Kind.B, s, 0), payload)
+        if split_backward:
+            progs[s](Task(Kind.W, s, 0), None)
+    jax.block_until_ready([(p.d_stage, p.d_io) for p in progs])
