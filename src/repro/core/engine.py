@@ -49,10 +49,26 @@ class DeadlockError(RuntimeError):
 
 @dataclasses.dataclass
 class StageStats:
+    """Per-stage time split of one run, in the run's clock seconds.
+
+    ``compute`` is time inside the stage's work (the callable on the thread
+    substrate); ``blocking`` is everything else up to the makespan, the tail
+    after the stage's last task included.  The thread substrate splits
+    ``blocking`` further: ``wait`` is time blocked in
+    ``Mailbox.wait_for_work`` with no task ready, and ``runtime`` is time in
+    the runtime's own code (mailbox lock, mailbox sync, arbitration,
+    ``begin``, ``complete``, building and sending envelopes).  Per stage,
+    ``wait + runtime + compute`` plus the tail (makespan - last task end)
+    closes to the makespan, short by the thread's start latency.  The DES
+    engine and the sim substrate leave ``wait`` and ``runtime`` at 0.
+    """
+
     compute: float = 0.0
     blocking: float = 0.0
     tp_coord: float = 0.0
     deferrals: int = 0
+    wait: float = 0.0
+    runtime: float = 0.0
 
 
 @dataclasses.dataclass

@@ -118,5 +118,6 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return out[:, :, :sq, :]

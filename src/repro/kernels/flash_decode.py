@@ -104,5 +104,6 @@ def flash_decode(q, k_cache, v_cache, length, *, window: int = 0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, 1, hd), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(jnp.asarray(length, jnp.int32).reshape(1), q, k_cache, v_cache)
     return out

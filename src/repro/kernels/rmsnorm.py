@@ -38,5 +38,6 @@ def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nr * block_rows, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale.reshape(1, d))
     return out[:rows].reshape(shape)

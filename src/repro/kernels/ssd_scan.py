@@ -101,4 +101,5 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((b, nh, s, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt, cum_cols, cum_rows, B, C, D.astype(jnp.float32))

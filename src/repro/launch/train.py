@@ -108,9 +108,10 @@ def build_trainer(cfg: ArchConfig, *, data: int, stages: int,
     def train_step(stage_params, io_params, opt_state, batch, step):
         metrics, grad_shard, expert_grads = exec_fn(
             stage_params, io_params, batch)
-        stage_params, io_params, opt_state, stats = opt_update(
-            stage_params, io_params, opt_state, grad_shard, expert_grads,
-            step)
+        with jax.named_scope("optimizer"):
+            stage_params, io_params, opt_state, stats = opt_update(
+                stage_params, io_params, opt_state, grad_shard,
+                expert_grads, step)
         return stage_params, io_params, opt_state, {**metrics, **stats}
 
     opt_state = jax.jit(opt_init)(stage_params, io_params)

@@ -2,7 +2,8 @@
 online cost tables.
 
 Layered strictly *on top of* the runtime (``repro.runtime.rrfp`` never
-imports this package except lazily from ``Trace.to_perfetto``):
+imports this package except lazily: ``Trace.to_perfetto``, and ``spans`` from
+the thread substrate's run loop):
 
   metrics     -- per-stage single-writer shards: counters, gauges,
                  log-bucketed histograms; aggregated at sync points
@@ -17,6 +18,9 @@ imports this package except lazily from ``Trace.to_perfetto``):
                  the critical-path graph predict the new makespan
   report      -- one-shot explain(trace) health report + CLI
   export      -- Chrome trace-event / Perfetto JSON rendering of traces
+  spans       -- profiler spans (``jax.profiler.TraceAnnotation`` once JAX
+                 is loaded) at the actor runtime's and stage programs'
+                 boundaries, on the device trace's clock
 
 See ``docs/observability.md`` for the metric catalogue and semantics.
 """

@@ -11,6 +11,11 @@ Backward is remat-based: B re-runs the stage forward under ``jax.grad`` of a
 scalarized objective (CE at the last stage, <y, g_in> elsewhere), so no
 activation stack is kept beyond each microbatch's stage input.
 
+Named scopes put the device trace's ops under the tick's op (``tick.F``,
+``tick.B``, ``tick.W``, ``tick.idle``), the ring exchange (``tick.exchange``)
+and, inside an op, ``embed``, ``layers``, ``ce_loss`` and a backward's
+``recompute``.
+
 Collective-order consistency across a stage row (the paper's §4.2 constraint)
 holds by construction: the table is uniform across the ``data`` axis, so all
 ranks of a "TP group" (here: a data row) enter identical branches — data-axis
@@ -139,6 +144,7 @@ def make_train_fn(
                 a["mrope"] = bm["mrope"]
             return a
 
+        @jax.named_scope("embed")
         def pipeline_embed(io_, bm):
             if cfg.embed_input:
                 x = bm["embeds"].astype(cfg.dtype)
@@ -148,6 +154,10 @@ def make_train_fn(
                 x = jnp.concatenate(
                     [x, bm["enc_embeds"].astype(cfg.dtype)], axis=1)
             return x
+
+        @jax.named_scope("layers")
+        def layers(sp_, io_, x, a):
+            return model.stage_forward(sp_, io_, x, a, rows)
 
         def loss_of(io_, y, bm):
             if cfg.encoder_layers:
@@ -183,7 +193,7 @@ def make_train_fn(
                 lambda: jax.lax.dynamic_index_in_dim(
                     state["act_buf"], mb % K_act, 0, keepdims=False),
             )
-            y = model.stage_forward(sp, io, x_in, a, rows)
+            y = layers(sp, io, x_in, a)
             loss_inc = jax.lax.cond(
                 stage == S - 1,
                 lambda: loss_of(io, y, bm),
@@ -198,10 +208,11 @@ def make_train_fn(
                 "send_act": (y, mb, stage < S - 1),
             }
 
+        @jax.named_scope("recompute")
         def scalar_objective(sp_, io_, x, g_in, bm, a):
             x0 = jax.lax.cond(
                 stage == 0, lambda: pipeline_embed(io_, bm).astype(dt), lambda: x)
-            y = model.stage_forward(sp_, io_, x0, a, rows)
+            y = layers(sp_, io_, x0, a)
             return jax.lax.cond(
                 stage == S - 1,
                 lambda: loss_of(io_, y, bm) * opts.loss_scale,
@@ -258,20 +269,28 @@ def make_train_fn(
                     state["d_io"], dio),
             }
 
+        branches = [jax.named_scope(name)(fn) for name, fn in (
+            ("tick.idle", idle_fn), ("tick.F", f_fn), ("tick.B", b_fn),
+            ("tick.W", w_fn))]
+
         def tick_body(t, state):
             # deliver messages sent at t-1 (one ring hop per direction)
             pa, pm, pv = state["send_act"]
-            ra = jax.lax.ppermute(pa, "model", fwd_perm)
-            rm = jax.lax.ppermute(pm, "model", fwd_perm)
-            rv = jax.lax.ppermute(pv.astype(jnp.int32), "model", fwd_perm) > 0
+            with jax.named_scope("tick.exchange"):
+                ra = jax.lax.ppermute(pa, "model", fwd_perm)
+                rm = jax.lax.ppermute(pm, "model", fwd_perm)
+                rv = jax.lax.ppermute(
+                    pv.astype(jnp.int32), "model", fwd_perm) > 0
             cur = jax.lax.dynamic_index_in_dim(
                 state["act_buf"], rm % K_act, 0, keepdims=False)
             act_buf = jax.lax.dynamic_update_index_in_dim(
                 state["act_buf"], jnp.where(rv, ra, cur), rm % K_act, 0)
             ga, gm, gv = state["send_grad"]
-            rga = jax.lax.ppermute(ga, "model", bwd_perm)
-            rgm = jax.lax.ppermute(gm, "model", bwd_perm)
-            rgv = jax.lax.ppermute(gv.astype(jnp.int32), "model", bwd_perm) > 0
+            with jax.named_scope("tick.exchange"):
+                rga = jax.lax.ppermute(ga, "model", bwd_perm)
+                rgm = jax.lax.ppermute(gm, "model", bwd_perm)
+                rgv = jax.lax.ppermute(
+                    gv.astype(jnp.int32), "model", bwd_perm) > 0
             curg = jax.lax.dynamic_index_in_dim(
                 state["grad_buf"], rgm % K_grad, 0, keepdims=False)
             grad_buf = jax.lax.dynamic_update_index_in_dim(
@@ -285,7 +304,7 @@ def make_train_fn(
             }
             op = ops_arr[stage, t]
             mb = mbs_arr[stage, t]
-            return jax.lax.switch(op, [idle_fn, f_fn, b_fn, w_fn], state, mb)
+            return jax.lax.switch(op, branches, state, mb)
 
         state = jax.lax.fori_loop(0, T, tick_body, zero_state)
 

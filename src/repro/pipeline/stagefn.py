@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.core.taskgraph import Kind, Task
 from repro.models.build import ArchModel
 from repro.models.layers import rmsnorm
+from repro.obs.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +47,7 @@ def default_ce_chunk(cfg, requested: int = 0) -> int:
 # ---------------------------------------------------------------------------
 # loss (shared with the executor)
 # ---------------------------------------------------------------------------
+@jax.named_scope("ce_loss")
 def chunked_ce_sum(model: ArchModel, io, y, labels, chunk: int):
     """Sum of token cross-entropies, scanned over token chunks (bounded
     logits working set; checkpointed so backward re-materializes per chunk)."""
@@ -80,6 +82,13 @@ def chunked_ce_sum(model: ArchModel, io, y, labels, chunk: int):
 # ---------------------------------------------------------------------------
 # per-stage callables
 # ---------------------------------------------------------------------------
+def _jit(fn, name: str):
+    """``jax.jit`` under ``name``: the lowered module, and so the device
+    trace's module line, reads ``jit_<name>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 class StageFns:
     """Jitted forward/backward per stage of a single-process pipeline.
 
@@ -95,6 +104,12 @@ class StageFns:
       (``argnums=(2,)``), on the critical inter-stage path;
     * ``weight_grad(s)(sp_s, io, x, g_in, bm) -> (d_stage, d_io)`` — the
       deferrable per-microbatch W task (``argnums=(0, 1)``), stage-local.
+
+    Each callable is jitted under its stage and op, so its module is
+    ``jit_stage{s}_F``, ``jit_stage{s}_B``, ``jit_stage{s}_dX`` or
+    ``jit_stage{s}_W``; inside, ops carry the scopes ``embed``, ``layers``
+    and ``ce_loss``, and a backward's re-run forward sits under
+    ``recompute``.
     """
 
     def __init__(self, model: ArchModel, opts: StageFnOptions):
@@ -127,32 +142,39 @@ class StageFns:
             return bm["embeds"].astype(cfg.dtype)
         return io["embed"][bm["tokens"]]
 
-    def _objective(self, stage: int, sp_s, io, x, g_in, bm):
+    def _stage_out(self, stage: int, sp_s, io, x, bm):
+        """The stage's layers on its input (the embedded tokens at stage 0)."""
         model, cfg = self.model, self.model.cfg
-        x0 = self._embed(io, bm).astype(cfg.dtype) if stage == 0 else x
-        y = model.stage_forward(sp_s, io, x0, self._aux(bm), model.rows(stage))
-        if stage == model.num_stages - 1:
-            return chunked_ce_sum(
-                model, io, y, bm["labels"], self.ce_chunk) * self.opts.loss_scale
-        return jnp.sum(y.astype(jnp.float32) * g_in.astype(jnp.float32))
+        if stage == 0:
+            with jax.named_scope("embed"):
+                x = self._embed(io, bm).astype(cfg.dtype)
+        with jax.named_scope("layers"):
+            return model.stage_forward(sp_s, io, x, self._aux(bm),
+                                       model.rows(stage))
+
+    def _objective(self, stage: int, sp_s, io, x, g_in, bm):
+        model = self.model
+        with jax.named_scope("recompute"):
+            y = self._stage_out(stage, sp_s, io, x, bm)
+            if stage == model.num_stages - 1:
+                return chunked_ce_sum(model, io, y, bm["labels"],
+                                      self.ce_chunk) * self.opts.loss_scale
+            return jnp.sum(y.astype(jnp.float32) * g_in.astype(jnp.float32))
 
     # ---- public --------------------------------------------------------
     def forward(self, stage: int):
         if stage not in self._fwd:
-            model, cfg = self.model, self.model.cfg
+            model = self.model
             last = stage == model.num_stages - 1
 
             def f(sp_s, io, x, bm):
-                x0 = (self._embed(io, bm).astype(cfg.dtype)
-                      if stage == 0 else x)
-                y = model.stage_forward(
-                    sp_s, io, x0, self._aux(bm), model.rows(stage))
+                y = self._stage_out(stage, sp_s, io, x, bm)
                 loss = (chunked_ce_sum(model, io, y, bm["labels"],
                                        self.ce_chunk)
                         if last else jnp.zeros((), jnp.float32))
                 return y, loss
 
-            self._fwd[stage] = jax.jit(f)
+            self._fwd[stage] = _jit(f, f"stage{stage}_F")
         return self._fwd[stage]
 
     def backward(self, stage: int):
@@ -164,7 +186,7 @@ class StageFns:
                     argnums=(0, 1, 2))(sp_s, io, x)
                 return dx, dsp, dio
 
-            self._bwd[stage] = jax.jit(b)
+            self._bwd[stage] = _jit(b, f"stage{stage}_B")
         return self._bwd[stage]
 
     def backward_dx(self, stage: int):
@@ -177,7 +199,7 @@ class StageFns:
                     argnums=(0,))(x)
                 return dx
 
-            self._bwd_dx[stage] = jax.jit(b_dx)
+            self._bwd_dx[stage] = _jit(b_dx, f"stage{stage}_dX")
         return self._bwd_dx[stage]
 
     def weight_grad(self, stage: int):
@@ -190,7 +212,7 @@ class StageFns:
                     argnums=(0, 1))(sp_s, io)
                 return dsp, dio
 
-            self._wgrad[stage] = jax.jit(w)
+            self._wgrad[stage] = _jit(w, f"stage{stage}_W")
         return self._wgrad[stage]
 
 
@@ -239,6 +261,9 @@ class ActorStageProgram:
     loss and gradients bitwise identical across any execution order of the
     same task set — the property the conformance suite checks between
     chaotic actor runs and the fixed-order reference executor.
+
+    Profiler spans: ``stage.init`` around the zeroed accumulators, and
+    ``stage.accumulate`` around each microbatch's grad and loss adds.
     """
 
     def __init__(self, fns: StageFns, stage: int, sp_s, io, batch: dict,
@@ -255,9 +280,10 @@ class ActorStageProgram:
         #: BFW: mb -> (x, g_in) held from B-time until the W task fires
         self.w_pending: dict[int, tuple[Any, Any]] = {}
         self.w_high_water = 0  # max outstanding W stashes (memory bound)
-        self.d_stage = jax.tree.map(jnp.zeros_like, sp_s)
-        self.d_io = jax.tree.map(jnp.zeros_like, io)
-        self.loss_acc = jnp.zeros((), jnp.float32)
+        with span("stage.init", stage=stage):
+            self.d_stage = jax.tree.map(jnp.zeros_like, sp_s)
+            self.d_io = jax.tree.map(jnp.zeros_like, io)
+            self.loss_acc = jnp.zeros((), jnp.float32)
         #: deterministic mode: mb -> stashed contributions, folded by finalize
         self._mb_loss: dict[int, Any] = {}
         self._mb_grads: dict[int, tuple[Any, Any]] = {}
@@ -270,8 +296,9 @@ class ActorStageProgram:
         if self.deterministic_reduction:
             self._mb_grads[mb] = (dsp, dio)
             return
-        self.d_stage = jax.tree.map(jnp.add, self.d_stage, dsp)
-        self.d_io = jax.tree.map(jnp.add, self.d_io, dio)
+        with span("stage.accumulate", stage=self.stage):
+            self.d_stage = jax.tree.map(jnp.add, self.d_stage, dsp)
+            self.d_io = jax.tree.map(jnp.add, self.d_io, dio)
 
     def finalize(self) -> "ActorStageProgram":
         """Fold stashed per-microbatch contributions in microbatch order.
@@ -325,7 +352,8 @@ class ActorStageProgram:
             if self.deterministic_reduction:
                 self._mb_loss[task.mb] = loss
             else:
-                self.loss_acc = self.loss_acc + loss
+                with span("stage.accumulate", stage=self.stage):
+                    self.loss_acc = self.loss_acc + loss
             self._g_dummy = jnp.zeros_like(y)
             return y
         if task.kind == Kind.B:

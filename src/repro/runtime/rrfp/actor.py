@@ -45,6 +45,10 @@ from repro.runtime.rrfp.messages import (
 )
 
 
+#: the profiler span around one work callable, by task kind
+_WORK_SPANS = {k: f"rrfp.{k.name}" for k in Kind}
+
+
 @dataclasses.dataclass
 class TaskTrace:
     """One dispatch record (start/end on the driver's clock)."""
@@ -382,11 +386,24 @@ class StageActor:
         detection), so abort/stop signals must notify the condition to be
         seen promptly (``Mailbox.stop`` does; the driver stops every
         mailbox when a sibling stage errors).
+
+        Time is counted where it is spent: ``stats.wait`` inside
+        ``wait_for_work``, ``stats.compute`` inside ``work_fn``, and
+        ``stats.runtime`` for the rest (the mailbox lock, sync, arbitration,
+        ``begin``, ``complete`` and the sends).  Each boundary is also a
+        profiler span (``repro.obs.spans``): ``rrfp.wait``, ``rrfp.F``/
+        ``rrfp.B``/``rrfp.W`` with ``stage``/``mb``, and ``rrfp.complete``.
         """
-        idle_since = clock()
+        from repro.obs.spans import span
+
+        stats = self.stats
+        #: end of the last interval counted into wait/compute/runtime
+        mark = clock()
+        idle_since = mark
         while not self.finished():
             if abort is not None and abort.is_set():
                 return
+            waited = 0.0
             with self.mailbox.cond:
                 task = None
                 while True:
@@ -407,45 +424,59 @@ class StageActor:
                             f"stage {self.idx} starved >{deadlock_timeout}s "
                             f"with {self._total - len(self.done)} tasks left; "
                             f"waiting on messages for {self.waiting_on()[:4]}")
-                    self.mailbox.wait_for_work(remaining)
+                    t_wait = clock()
+                    with span("rrfp.wait", stage=self.idx):
+                        self.mailbox.wait_for_work(remaining)
+                    dt = clock() - t_wait
+                    waited += dt
+                    stats.wait += dt
                 if task is None:  # finished() flipped
                     return
                 payload = self.begin(task, now=clock(), info=sel_info)
             start = clock()
-            self.stats.blocking += max(0.0, start - idle_since)
+            stats.blocking += max(0.0, start - idle_since)
+            stats.runtime += start - mark - waited
             self.exec_since = _time.monotonic()
             try:
-                out_payload = work_fn(task, payload)
+                with span(_WORK_SPANS[task.kind], stage=self.idx,
+                          mb=task.mb):
+                    out_payload = work_fn(task, payload)
             finally:
                 self.exec_since = None
             end = clock()
-            self.stats.compute += end - start
-            with self.mailbox.cond:
-                if self.halted:
-                    # killed mid-execution (link failure on a live stage):
-                    # the successor incarnation re-executes this task, so
-                    # committing it here would double-complete it
-                    return
-                succs = self.complete(task, now=end, dur=end - start)
-                self._n_complete += 1
-                if (self.swap_table is not None
-                        and self._n_complete == self.swap_after):
-                    # quiesce point: this stage holds no in-flight task
-                    self.set_hint_table(self.swap_table, now=end)
-                self.mailbox.touch()
-            self.traces.append(TaskTrace(task, start, end))
-            idle_since = end
-            if isinstance(out_payload, EdgePayloads):
-                # a missing edge entry would silently deliver payload=None
-                # (downstream substitutes a zero gradient) — fail fast
-                missing = [t.stage for t in succs
-                           if t.stage not in out_payload]
-                if missing:
-                    raise ValueError(
-                        f"stage {self.idx}: {task!r} returned EdgePayloads "
-                        f"without entries for successor stage(s) {missing}")
-            for succ in succs:
-                for env in envelopes_for(
-                        succ, self.idx, tp_degree, send_time=end,
-                        payload=payload_for_edge(out_payload, succ.stage)):
-                    transport.send(env, now=end)
+            stats.compute += end - start
+            with span("rrfp.complete", stage=self.idx):
+                with self.mailbox.cond:
+                    if self.halted:
+                        # killed mid-execution (link failure on a live
+                        # stage): the successor incarnation re-executes this
+                        # task, so committing it here would double-complete
+                        return
+                    succs = self.complete(task, now=end, dur=end - start)
+                    self._n_complete += 1
+                    if (self.swap_table is not None
+                            and self._n_complete == self.swap_after):
+                        # quiesce point: this stage holds no in-flight task
+                        self.set_hint_table(self.swap_table, now=end)
+                    self.mailbox.touch()
+                self.traces.append(TaskTrace(task, start, end))
+                idle_since = end
+                if isinstance(out_payload, EdgePayloads):
+                    # a missing edge entry would silently deliver
+                    # payload=None (downstream substitutes a zero gradient)
+                    # — fail fast
+                    missing = [t.stage for t in succs
+                               if t.stage not in out_payload]
+                    if missing:
+                        raise ValueError(
+                            f"stage {self.idx}: {task!r} returned "
+                            f"EdgePayloads without entries for successor "
+                            f"stage(s) {missing}")
+                for succ in succs:
+                    for env in envelopes_for(
+                            succ, self.idx, tp_degree, send_time=end,
+                            payload=payload_for_edge(out_payload,
+                                                     succ.stage)):
+                        transport.send(env, now=end)
+            mark = clock()
+            stats.runtime += mark - end

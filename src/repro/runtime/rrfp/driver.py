@@ -815,7 +815,14 @@ class ActorDriver:
 
         ``work_fn(task, payload)`` (or one callable per stage) performs the
         actual computation and returns the payload for the outgoing message.
+        The whole run is the profiler span ``rrfp.run``.
         """
+        from repro.obs.spans import span
+
+        with span("rrfp.run"):
+            return self._run_threaded(work_fn)
+
+    def _run_threaded(self, work_fn) -> RunResult:
         import queue as _queue
         import time as _time
 

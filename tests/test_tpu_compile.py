@@ -7,6 +7,7 @@ cannot see those faults.  The topology is described inside a fixture, never
 at import: only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,13 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
+def _kernel_op(text: str, name: str) -> bool:
+    """Whether compiled HLO ``text`` runs the Pallas kernel ``name`` under
+    that op name, the name a device trace gives its calls."""
+    return re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call",
+                     text) is not None
+
+
 def _compile(fn, *shapes, sharding=None, dtype=jnp.bfloat16):
     args = [s if isinstance(s, jax.ShapeDtypeStruct)
             else jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
@@ -54,7 +62,7 @@ def test_flash_attention_fwd_compiles(one_chip, b, h, s, hd):
     c = _compile(lambda q, k, v: flash_attention.flash_attention_fwd(q, k, v),
                  (b, h, s, hd), (b, h, s, hd), (b, h, s, hd),
                  sharding=one_chip)
-    assert "tpu_custom_call" in c.as_text()
+    assert _kernel_op(c.as_text(), "flash_attention_fwd")
 
 
 def test_flash_decode_compiles(one_chip):
@@ -62,13 +70,13 @@ def test_flash_decode_compiles(one_chip):
     c = _compile(lambda q, k, v, n: flash_decode.flash_decode(q, k, v, n),
                  (8, 32, 1, 128), (8, 8, 4096, 128), (8, 8, 4096, 128),
                  length, sharding=one_chip)
-    assert "tpu_custom_call" in c.as_text()
+    assert _kernel_op(c.as_text(), "flash_decode")
 
 
 def test_rmsnorm_compiles(one_chip):
     c = _compile(lambda x, sc: rmsnorm.rmsnorm(x, sc),
                  (2048, SMOKE.d_model), (SMOKE.d_model,), sharding=one_chip)
-    assert "tpu_custom_call" in c.as_text()
+    assert _kernel_op(c.as_text(), "rmsnorm")
 
 
 def test_ssd_scan_compiles_at_zamba2_widths(one_chip):
@@ -83,7 +91,7 @@ def test_ssd_scan_compiles_at_zamba2_widths(one_chip):
             x, dt, A, B, C, D, chunk=cfg.ssm.chunk),
         (b, nh, s, hd), f32(b, s, nh), f32(nh), (b, s, ds), (b, s, ds),
         f32(nh), sharding=one_chip)
-    assert "tpu_custom_call" in c.as_text()
+    assert _kernel_op(c.as_text(), "ssd_scan")
 
 
 def test_smoke_layer_takes_the_kernel_on_tpu_only(one_chip):
@@ -105,7 +113,9 @@ def test_smoke_layer_takes_the_kernel_on_tpu_only(one_chip):
         jax.ShapeDtypeStruct((1, seq, SMOKE.d_model), SMOKE.dtype,
                              sharding=sh))
     tpu = jax.jit(step).lower(*on(one_chip)).compile().as_text()
-    assert "tpu_custom_call" in tpu
+    # the attention forward keeps the op name the benchmark's kernel reader
+    # (chipbench/kernels/attn_fwd.py) matches inside a whole layer
+    assert _kernel_op(tpu, "flash_attention_fwd")
     cpu_sharding = SingleDeviceSharding(jax.devices("cpu")[0])
     cpu = jax.jit(step).lower(*on(cpu_sharding)).as_text()
     assert "tpu_custom_call" not in cpu
