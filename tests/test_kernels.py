@@ -13,6 +13,7 @@ try:
 except ModuleNotFoundError:  # deterministic fallback (tests/_hyp_stub.py)
     from _hyp_stub import given, settings, strategies as st
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 
 
@@ -35,6 +36,7 @@ TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
         (1, 384, 8, 1, 128, 0),     # MQA (granite-style kv=1)
         (2, 160, 4, 4, 64, 64),     # sliding window (gemma3-style)
         (1, 96, 4, 2, 32, 0),       # smaller than one block
+        (1, 256, 4, 4, 96, 0),      # head 96 (gpt3-large, the chip cells)
     ],
 )
 def test_flash_attention_matches_oracle(b, sq, hq, hkv, hd, window, dtype):
@@ -49,6 +51,76 @@ def test_flash_attention_matches_oracle(b, sq, hq, hkv, hd, window, dtype):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         atol=TOL[dtype], rtol=TOL[dtype])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "sq,hq,hkv,hd,causal,window,bq,bk",
+    [
+        (1024, 2, 2, 96, True, 0, 256, 256),   # 4x4 blocks, diagonal clamp
+        (1000, 2, 1, 64, True, 0, 256, 128),   # ragged tail, bq > bk, MQA
+        (640, 4, 2, 96, True, 0, 128, 256),    # bq < bk, GQA
+        (1024, 2, 2, 64, True, 300, 128, 128),  # window spans 4 kv blocks
+        (1000, 2, 2, 64, False, 0, 256, 256),  # no causal mask, ragged tail
+    ],
+)
+def test_flash_attention_small_blocks_match_oracle(sq, hq, hkv, hd, causal,
+                                                   window, bq, bk, dtype):
+    """Several q and kv blocks a head: the kv clamp, the skipped blocks and
+    the mask applied only on the blocks that straddle an edge."""
+    rng = np.random.default_rng(sq + 7 * window + bq)
+    q = rand(rng, 1, sq, hq, hd, dtype=dtype)
+    k = rand(rng, 1, sq, hkv, hd, dtype=dtype)
+    v = rand(rng, 1, sq, hkv, hd, dtype=dtype)
+    pos = jnp.arange(sq)[None]
+    want = ref.attention_ref(q, k, v, pos, causal, window)
+    t = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
+    got = t(fa.flash_attention_fwd(
+        (t(q) * hd**-0.5).astype(dtype), t(k), t(v), causal=causal,
+        window=window, block_q=bq, block_k=bk, interpret=True))
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=TOL[dtype], rtol=TOL[dtype])
+
+
+@pytest.mark.parametrize(
+    "sq,bq,bk,causal,window",
+    [(2048, 1024, 1024, True, 0), (2048, 512, 512, True, 0),
+     (1000, 256, 128, True, 0),
+     (640, 128, 256, True, 0), (4096, 512, 512, True, 1024),
+     (1024, 128, 128, True, 300), (1000, 256, 256, False, 0),
+     (1000, 128, 256, False, 200), (96, 96, 96, True, 0)],
+)
+def test_kv_block_range_is_exactly_the_blocks_attended(sq, bq, bk, causal,
+                                                       window):
+    """The clamped kv range holds every block with an attended (q, k) pair
+    of the q block, and no block above the diagonal or before the window."""
+    nq, nk = -(-sq // bq), -(-sq // bk)
+    qp = np.arange(nq * bq)[:, None]
+    kp = np.arange(sq)[None, :]
+    attend = np.ones((nq * bq, sq), bool)
+    if causal:
+        attend &= qp >= kp
+    if window:
+        attend &= qp - kp < window
+    for qi in range(nq):
+        lo, hi = (int(x) for x in fa.kv_block_range(
+            qi, block_q=bq, block_k=bk, num_k_blocks=nk, causal=causal,
+            window=window))
+        rows = attend[qi * bq:(qi + 1) * bq]
+        used = [ki for ki in range(nk)
+                if rows[:, ki * bk:(ki + 1) * bk].any()]
+        assert (lo, hi) == (used[0], used[-1]), (qi, lo, hi, used)
+        assert used == list(range(lo, hi + 1))
+
+
+@pytest.mark.parametrize(
+    "seq,window,want",
+    [(2048, 0, 1024), (4096, 0, 1024), (100, 0, 100), (4096, 1024, 512),
+     (4096, 4096, 1024), (4096, 300, 128), (160, 64, 128), (96, 64, 96)],
+)
+def test_default_block(seq, window, want):
+    assert fa.default_block(seq, window) == want
 
 
 def test_xla_blocked_attention_matches_oracle():

@@ -65,6 +65,20 @@ def test_flash_attention_fwd_compiles(one_chip, b, h, s, hd):
     assert _kernel_op(c.as_text(), "flash_attention_fwd")
 
 
+@pytest.mark.parametrize("local", [True, False])
+def test_flash_attention_fwd_compiles_at_gemma3_widths(one_chip, local):
+    """gemma3-4b's local (window 1024) and global layers: head 256, 8 q
+    heads on 4 kv heads; the default blocks must fit the chip's VMEM at the
+    widest head."""
+    cfg = registry.get_arch("gemma3-4b")
+    hd, w = cfg.resolved_head_dim, cfg.sliding_window if local else 0
+    q = (1, cfg.num_heads, 4096, hd)
+    kv = (1, cfg.num_kv_heads, 4096, hd)
+    c = _compile(lambda q, k, v: flash_attention.flash_attention_fwd(
+        q, k, v, window=w), q, kv, kv, sharding=one_chip)
+    assert _kernel_op(c.as_text(), "flash_attention_fwd")
+
+
 def test_flash_decode_compiles(one_chip):
     length = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     c = _compile(lambda q, k, v, n: flash_decode.flash_decode(q, k, v, n),
