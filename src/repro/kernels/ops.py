@@ -183,12 +183,13 @@ def _ssd_xla_chunked(x, dt, A, B, C, D, chunk: int):
     g = jnp.einsum("bcid,bcjd->bcij", Cc, Bc,
                    preferred_element_type=jnp.float32)  # [b, nc, Q, Q]
     ii = jnp.arange(chunk)
-    tri = ii[:, None] >= ii[None, :]
-    decay = jnp.where(
-        tri[None, None, :, :, None],
-        jnp.exp(cum[:, :, :, None, :] - cum[:, :, None, :, :]),
-        0.0,
-    )  # [b, nc, Q, Q, nh]
+    tri = (ii[:, None] >= ii[None, :])[None, None, :, :, None]
+    # the exponent is masked before exp: above the diagonal cum_i - cum_j is
+    # a positive sum of up to chunk - 1 dts, whose exp overflows float32 past
+    # 88.7, and the backward would multiply that inf by a zero
+    decay = jnp.exp(jnp.where(
+        tri, cum[:, :, :, None, :] - cum[:, :, None, :, :],
+        -jnp.inf))  # [b, nc, Q, Q, nh]
     w = (g[..., None] * decay * dtf[:, :, None, :, :]).astype(ct)
     y_intra = jnp.einsum("bcijn,bcjnd->bcind", w, xc.astype(ct),
                          preferred_element_type=jnp.float32)

@@ -50,7 +50,9 @@ def _kernel(x_ref, dt_ref, cumc_ref, cumr_ref, b_ref, c_ref, d_ref, y_ref,
                             preferred_element_type=jnp.float32)  # [Q, Q]
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(ii >= jj, jnp.exp(cum - cum_row), 0.0)
+    # masked before exp, as in ops._ssd_xla_chunked: no exp of the positive
+    # differences above the diagonal
+    decay = jnp.exp(jnp.where(ii >= jj, cum - cum_row, -jnp.inf))
     y = jax.lax.dot_general(g * decay, x * dt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [Q, hd]
     # inter-chunk: y[i] += exp(cum_i) * C_i @ state^T

@@ -48,6 +48,12 @@ from repro.models.layers import (
 )
 
 
+def _shared_block(io, x, positions, cfg: ArchConfig):
+    """The shared attention block (zamba2), under its own scope."""
+    with jax.named_scope("shared_blk"):
+        return decoder_layer(io["shared_blk"], x, positions, cfg)
+
+
 def _tree_stack(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
@@ -227,7 +233,7 @@ class ArchModel:
             if cfg.shared_attn_period:
                 x = jax.lax.cond(
                     (sh > 0) & (en > 0),
-                    lambda x: decoder_layer(io["shared_blk"], x, aux["positions"], cfg),
+                    lambda x: _shared_block(io, x, aux["positions"], cfg),
                     lambda x: x,
                     x,
                 )
@@ -251,7 +257,7 @@ class ArchModel:
                 if not en:
                     return x
                 if cfg.shared_attn_period and sh:
-                    x = decoder_layer(io["shared_blk"], x, aux["positions"], cfg)
+                    x = _shared_block(io, x, aux["positions"], cfg)
                 return branches[tid](p_slot, io, x, aux)
 
             policy = (jax.checkpoint_policies.save_only_these_names(
@@ -410,6 +416,7 @@ class ArchModel:
             p_slot, cache_slot, tid, en, sh = scan_in
             if cfg.shared_attn_period:
                 # the shared block's KV cache rides in the slot's k/v fields
+                @jax.named_scope("shared_blk")
                 def shared_apply(x, kvc):
                     return decoder_layer_decode(io["shared_blk"], x, kvc, pos, cfg)
 

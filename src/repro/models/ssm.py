@@ -51,6 +51,7 @@ def _split_proj(proj, cfg: ArchConfig):
     return z, xbc, dt, (di, nh, ds)
 
 
+@jax.named_scope("mamba")
 def mamba_layer(p, x, cfg: ArchConfig):
     """x: [b, s, d] -> [b, s, d] (pre-norm residual handled here)."""
     from repro.models.layers import rmsnorm
@@ -64,10 +65,11 @@ def mamba_layer(p, x, cfg: ArchConfig):
     xs, B, C = jnp.split(xbc, [di, di + ds], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["a_log"])
-    y = ops.ssd(
-        xs.reshape(b, s, nh, ssm.head_dim), dt, A, B, C, p["d_skip"],
-        chunk=ssm.chunk,
-    ).reshape(b, s, di)
+    with jax.named_scope("ssd"):
+        y = ops.ssd(
+            xs.reshape(b, s, nh, ssm.head_dim), dt, A, B, C, p["d_skip"],
+            chunk=ssm.chunk,
+        ).reshape(b, s, di)
     y = rmsnorm(y * jax.nn.silu(z), p["gate_ln"], cfg.norm_eps)
     return x + y @ p["out_proj"]
 

@@ -242,6 +242,38 @@ def test_ssd_matches_sequential_oracle(b, s, nh, hd, ds, chunk, dtype):
         atol=atol, rtol=rtol)
 
 
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+@pytest.mark.parametrize(
+    "dt_lo,dt_hi",
+    [
+        (0.0, 0.1),
+        (1.5, 2.5),  # |A| dt over 63 positions: 95-236, past f32 exp's 88.7
+    ],
+)
+def test_ssd_grad_matches_sequential_oracle(dt_lo, dt_hi, backend):
+    rng = np.random.default_rng(17)
+    b, s, nh, hd, ds, chunk = 1, 128, 2, 16, 8, 64
+    x = rand(rng, b, s, nh, hd)
+    dt = jnp.asarray(rng.uniform(dt_lo, dt_hi, (b, s, nh)), jnp.float32)
+    A = -jnp.asarray(rng.uniform(1.0, 1.5, nh), jnp.float32)
+    B = rand(rng, b, s, ds)
+    C = rand(rng, b, s, ds)
+    D = rand(rng, nh)
+    g = rand(rng, b, s, nh, hd)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * g),
+                        argnums=tuple(range(6)))(x, dt, A, B, C, D)
+
+    want = grads(ref.ssd_ref)
+    got = grads(lambda *a: ops.ssd(*a, chunk=chunk, backend=backend))
+    for name, w, v in zip("x dt A B C D".split(), want, got):
+        assert bool(jnp.isfinite(v).all()), name
+        np.testing.assert_allclose(np.asarray(v), np.asarray(w), rtol=2e-4,
+                                   atol=2e-4 * float(jnp.max(jnp.abs(w))),
+                                   err_msg=name)
+
+
 def test_ssd_decode_step_consistent_with_scan():
     rng = np.random.default_rng(5)
     b, s, nh, hd, ds = 2, 16, 2, 16, 8

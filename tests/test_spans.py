@@ -3,6 +3,7 @@ profiler spans at the same boundaries (``repro.obs.spans``)."""
 import glob
 import os
 import pathlib
+import re
 import subprocess
 import sys
 import time
@@ -164,3 +165,36 @@ def test_named_scopes_in_the_lowered_programs():
     for scope in ("tick.F/", "tick.B/", "tick.exchange/",
                   "tick.B/jvp(recompute)/", "ce_loss/", "optimizer/"):
         assert scope in step, scope
+
+
+def test_zamba2_scopes_in_the_lowered_stage_programs():
+    """A hybrid model's stage callables carry ``shared_blk`` around the
+    shared attention block, ``mamba`` around each Mamba-2 layer and ``ssd``
+    around its scan, forward and in the backward's re-run forward."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import registry
+    from repro.models.build import build
+    from repro.pipeline.stagefn import StageFnOptions, StageFns
+
+    cfg = dataclasses.replace(registry.reduced_config("zamba2-1.2b", 2),
+                              layer_pattern=("mamba",) * 2)
+    seq = 16
+    model = build(cfg, num_stages=1)
+    key = jax.random.key(0)
+    sp = jax.tree.map(lambda x: x[0], model.init_stage_params(key))
+    io = model.init_io_params(key)
+    toks = jnp.zeros((1, seq), jnp.int32)
+    bm = {"tokens": toks, "labels": toks}
+    fns = StageFns(model, StageFnOptions(mb_rows=1, seq_len=seq))
+    fwd = fns.forward(0).lower(sp, io, None, bm).as_text(debug_info=True)
+    g = jnp.zeros((1, seq, cfg.d_model), cfg.dtype)
+    bwd = fns.backward(0).lower(sp, io, None, g, bm).as_text(debug_info=True)
+    for text, under in ((fwd, "jit(stage0_F)/layers/"),
+                        (bwd, "jit(stage0_B)/jvp(recompute)/layers/")):
+        for scope in ("shared_blk/", "mamba/", "mamba/ssd/"):
+            assert re.search(re.escape(under) + r"(checkpoint/)?"
+                             + re.escape(scope), text), (under, scope)
