@@ -14,8 +14,11 @@ The spans the program emits (see ``docs/observability.md``):
   rrfp.F/B/W        one stage callable call, ``stage=``/``mb=``
                     (``StageStats.compute``)
   rrfp.complete     completion and its sends (part of ``StageStats.runtime``)
-  stage.init        ``ActorStageProgram`` set-up: zeroed accumulators
-  stage.accumulate  the per-microbatch grad and loss adds
+  stage.init        ``ActorStageProgram`` set-up: one jitted call that
+                    builds the zeroed accumulators
+  stage.accumulate  the per-microbatch loss add (the grads are folded
+                    inside the B/W callable), or in deterministic mode
+                    the loss and grad stash
 """
 from __future__ import annotations
 

@@ -14,6 +14,8 @@ stage, <y, g_in> elsewhere).
 ``work_fn(task, payload)`` protocol: it holds the stage's residual store
 (per-microbatch forward inputs) and gradient accumulators, consumes arrived
 activations/gradients as message payloads, and emits the outgoing payload.
+Its backward callables take the accumulators as a donated argument and fold
+each microbatch's gradients into them inside the same jitted program.
 """
 from __future__ import annotations
 
@@ -82,11 +84,65 @@ def chunked_ce_sum(model: ArchModel, io, y, labels, chunk: int):
 # ---------------------------------------------------------------------------
 # per-stage callables
 # ---------------------------------------------------------------------------
-def _jit(fn, name: str):
+def _jit(fn, name: str, **jit_kwargs):
     """``jax.jit`` under ``name``: the lowered module, and so the device
     trace's module line, reads ``jit_<name>``."""
     fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+    return jax.jit(fn, **jit_kwargs)
+
+
+def _fold(acc, grads):
+    """``acc + grads`` leaf by leaf, each in its accumulator's dtype.  Inside
+    a jitted backward XLA can fuse the add into the gradient's producer (the
+    embedding's scatter-add lands in the accumulator itself), and a gradient
+    that is identically zero (an ``io`` leaf the stage never reads) leaves
+    its donated accumulator in place, unwritten."""
+    return jax.tree.map(jnp.add, acc, grads)
+
+
+@jax.custom_vjp
+def embed_rows(table, tokens):
+    """``table[tokens]``, whose backward sums each distinct token's rows in
+    float32 and adds them to the table's gradient once.
+
+    The plain gather's backward scatter-adds every position's row in the
+    table's dtype, one rounding per position; folded into an accumulator
+    (``StageFns.backward_into``) the scatter lands in the accumulator
+    itself, where a frequent token's rows would round at the magnitude of
+    the whole step's sum and the small ones would be lost in bf16."""
+    return table[tokens]
+
+
+def _embed_rows_fwd(table, tokens):
+    return table[tokens], (table, tokens)
+
+
+def _embed_rows_bwd(res, ct):
+    table, tokens = res
+    flat = tokens.reshape(-1)
+    n, vocab = flat.shape[0], table.shape[0]
+    rows, slot = jnp.unique(flat, size=n, fill_value=vocab,
+                            return_inverse=True)
+    sums = jax.ops.segment_sum(
+        ct.reshape(n, -1).astype(jnp.float32), slot.reshape(-1),
+        num_segments=n)
+    # the slots past the distinct tokens hold row ``vocab``: dropped
+    return (jnp.zeros_like(table).at[rows].add(
+        sums.astype(table.dtype), mode="drop", indices_are_sorted=True),
+        None)
+
+
+embed_rows.defvjp(_embed_rows_fwd, _embed_rows_bwd)
+
+
+def _zero_accumulators(sp_s, io):
+    """``((d_stage, d_io), loss_acc)``: zeroed gradient accumulators in each
+    parameter's dtype and a float32 loss, built in one program."""
+    return ((jax.tree.map(jnp.zeros_like, sp_s),
+             jax.tree.map(jnp.zeros_like, io)), jnp.zeros((), jnp.float32))
+
+
+zero_accumulators = _jit(_zero_accumulators, "stage_init")
 
 
 class StageFns:
@@ -105,11 +161,17 @@ class StageFns:
     * ``weight_grad(s)(sp_s, io, x, g_in, bm) -> (d_stage, d_io)`` — the
       deferrable per-microbatch W task (``argnums=(0, 1)``), stage-local.
 
+    The accumulating forms fold the gradients into ``acc``, the
+    ``(d_stage, d_io)`` pair, which they take donated, in the same program:
+
+    * ``backward_into(s)(acc, sp_s, io, x, g_in, bm) -> (dx, acc + grads)``;
+    * ``weight_grad_into(s)(acc, sp_s, io, x, g_in, bm) -> acc + grads``.
+
     Each callable is jitted under its stage and op, so its module is
     ``jit_stage{s}_F``, ``jit_stage{s}_B``, ``jit_stage{s}_dX`` or
-    ``jit_stage{s}_W``; inside, ops carry the scopes ``embed``, ``layers``
-    and ``ce_loss``, and a backward's re-run forward sits under
-    ``recompute``.
+    ``jit_stage{s}_W`` (an accumulating form under its plain form's name);
+    inside, ops carry the scopes ``embed``, ``layers`` and ``ce_loss``, and a
+    backward's re-run forward sits under ``recompute``.
     """
 
     def __init__(self, model: ArchModel, opts: StageFnOptions):
@@ -121,6 +183,8 @@ class StageFns:
         self._bwd: dict[int, Any] = {}
         self._bwd_dx: dict[int, Any] = {}
         self._wgrad: dict[int, Any] = {}
+        self._bwd_into: dict[int, Any] = {}
+        self._wgrad_into: dict[int, Any] = {}
 
     # ---- helpers -------------------------------------------------------
     def _aux(self, bm: dict) -> dict:
@@ -140,7 +204,7 @@ class StageFns:
         cfg = self.model.cfg
         if cfg.embed_input:
             return bm["embeds"].astype(cfg.dtype)
-        return io["embed"][bm["tokens"]]
+        return embed_rows(io["embed"], bm["tokens"])
 
     def _stage_out(self, stage: int, sp_s, io, x, bm):
         """The stage's layers on its input (the embedded tokens at stage 0)."""
@@ -161,6 +225,20 @@ class StageFns:
                                       self.ce_chunk) * self.opts.loss_scale
             return jnp.sum(y.astype(jnp.float32) * g_in.astype(jnp.float32))
 
+    def _fused_grads(self, stage: int, sp_s, io, x, g_in, bm):
+        """``(dx, d_stage, d_io)`` of the stage's objective."""
+        dsp, dio, dx = jax.grad(
+            lambda sp_, io_, x_: self._objective(
+                stage, sp_, io_, x_, g_in, bm),
+            argnums=(0, 1, 2))(sp_s, io, x)
+        return dx, dsp, dio
+
+    def _weight_grads(self, stage: int, sp_s, io, x, g_in, bm):
+        """``(d_stage, d_io)`` of the stage's objective."""
+        return jax.grad(
+            lambda sp_, io_: self._objective(stage, sp_, io_, x, g_in, bm),
+            argnums=(0, 1))(sp_s, io)
+
     # ---- public --------------------------------------------------------
     def forward(self, stage: int):
         if stage not in self._fwd:
@@ -180,14 +258,21 @@ class StageFns:
     def backward(self, stage: int):
         if stage not in self._bwd:
             def b(sp_s, io, x, g_in, bm):
-                dsp, dio, dx = jax.grad(
-                    lambda sp_, io_, x_: self._objective(
-                        stage, sp_, io_, x_, g_in, bm),
-                    argnums=(0, 1, 2))(sp_s, io, x)
-                return dx, dsp, dio
+                return self._fused_grads(stage, sp_s, io, x, g_in, bm)
 
             self._bwd[stage] = _jit(b, f"stage{stage}_B")
         return self._bwd[stage]
+
+    def backward_into(self, stage: int):
+        """Fused backward that folds its grads into the donated ``acc``."""
+        if stage not in self._bwd_into:
+            def b(acc, sp_s, io, x, g_in, bm):
+                dx, dsp, dio = self._fused_grads(stage, sp_s, io, x, g_in, bm)
+                return dx, _fold(acc, (dsp, dio))
+
+            self._bwd_into[stage] = _jit(b, f"stage{stage}_B",
+                                         donate_argnums=0)
+        return self._bwd_into[stage]
 
     def backward_dx(self, stage: int):
         """dX-only backward (the B task of the BFW decomposition)."""
@@ -206,14 +291,21 @@ class StageFns:
         """Per-microbatch weight gradient (the deferrable W task)."""
         if stage not in self._wgrad:
             def w(sp_s, io, x, g_in, bm):
-                dsp, dio = jax.grad(
-                    lambda sp_, io_: self._objective(
-                        stage, sp_, io_, x, g_in, bm),
-                    argnums=(0, 1))(sp_s, io)
-                return dsp, dio
+                return self._weight_grads(stage, sp_s, io, x, g_in, bm)
 
             self._wgrad[stage] = _jit(w, f"stage{stage}_W")
         return self._wgrad[stage]
+
+    def weight_grad_into(self, stage: int):
+        """Weight gradient folded into the donated ``acc`` (the W task)."""
+        if stage not in self._wgrad_into:
+            def w(acc, sp_s, io, x, g_in, bm):
+                return _fold(acc, self._weight_grads(stage, sp_s, io, x,
+                                                     g_in, bm))
+
+            self._wgrad_into[stage] = _jit(w, f"stage{stage}_W",
+                                           donate_argnums=0)
+        return self._wgrad_into[stage]
 
 
 def microbatch(batch: dict, mb: int, mb_rows: int) -> dict:
@@ -237,33 +329,42 @@ class ActorStageProgram:
     F: consume the upstream activation payload (None at stage 0), run the
     jitted forward, stash the stage input for remat-backward, emit y.
     B (fused): consume the downstream gradient payload (None at the last
-    stage), re-run forward under grad, accumulate parameter grads, emit dx.
+    stage), re-run forward under grad, fold the parameter grads into the
+    accumulators inside the same jitted call, emit dx.
 
     With ``split_backward=True`` (the BFW decomposition):
 
     B: run the dX-only backward, stash the (x, g_in) pair for the W task,
     emit dx.  Stage 0 skips the dX computation entirely — no stage consumes
     its input gradient.
-    W: consume the stashed pair, run the weight-grad callable, accumulate
-    ``d_stage``/``d_io``.  W emits no payload: its result is stage-local
-    (``PipelineSpec.message_successor`` is None for W, so no envelope is
-    ever sent and no TP admission gate applies).
+    W: consume the stashed pair, run the weight-grad callable, folding into
+    ``d_stage``/``d_io`` as B does.  W emits no payload: its result is
+    stage-local (``PipelineSpec.message_successor`` is None for W, so no
+    envelope is ever sent and no TP admission gate applies).
 
     The running loss is accumulated as a device array — reading
     ``loss_sum`` materializes it (one sync), so the F hot path never blocks
     on the device.
 
+    In the default eager mode the accumulators are donated to each B (W
+    under split backward) call, which returns them with the microbatch's
+    grads added: the previous ``d_stage``/``d_io`` arrays are deleted, so
+    read them only after the run.  ``folded_in_callable`` counts the
+    microbatches folded so, M a step in eager mode.
+
     With ``deterministic_reduction=True`` the per-microbatch loss and grad
     contributions are *stashed* instead of folded in eagerly, and
-    :meth:`finalize` sums them in microbatch order.  Floating-point addition
-    is not associative, so the default eager accumulation is bit-sensitive
-    to the runtime's dispatch order; the deterministic mode makes the final
-    loss and gradients bitwise identical across any execution order of the
-    same task set — the property the conformance suite checks between
-    chaotic actor runs and the fixed-order reference executor.
+    :meth:`finalize` sums them in microbatch order (``folded_in_callable``
+    stays 0).  Floating-point addition is not associative, so the default
+    eager accumulation is bit-sensitive to the runtime's dispatch order; the
+    deterministic mode makes the final loss and gradients bitwise identical
+    across any execution order of the same task set — the property the
+    conformance suite checks between chaotic actor runs and the fixed-order
+    reference executor.
 
-    Profiler spans: ``stage.init`` around the zeroed accumulators, and
-    ``stage.accumulate`` around each microbatch's grad and loss adds.
+    Profiler spans: ``stage.init`` around the one jitted call that builds
+    the zeroed accumulators, and ``stage.accumulate`` around each
+    microbatch's loss add, or in deterministic mode its loss and grad stash.
     """
 
     def __init__(self, fns: StageFns, stage: int, sp_s, io, batch: dict,
@@ -281,9 +382,10 @@ class ActorStageProgram:
         self.w_pending: dict[int, tuple[Any, Any]] = {}
         self.w_high_water = 0  # max outstanding W stashes (memory bound)
         with span("stage.init", stage=stage):
-            self.d_stage = jax.tree.map(jnp.zeros_like, sp_s)
-            self.d_io = jax.tree.map(jnp.zeros_like, io)
-            self.loss_acc = jnp.zeros((), jnp.float32)
+            (self.d_stage, self.d_io), self.loss_acc = zero_accumulators(
+                sp_s, io)
+        #: microbatches whose grads the B/W callable folded in itself
+        self.folded_in_callable = 0
         #: deterministic mode: mb -> stashed contributions, folded by finalize
         self._mb_loss: dict[int, Any] = {}
         self._mb_grads: dict[int, tuple[Any, Any]] = {}
@@ -292,13 +394,9 @@ class ActorStageProgram:
         self._grads_folded: int | None = None
         self._g_dummy = None
 
-    def _add_grads(self, mb: int, dsp, dio) -> None:
-        if self.deterministic_reduction:
-            self._mb_grads[mb] = (dsp, dio)
-            return
+    def _stash_grads(self, mb: int, dsp, dio) -> None:
         with span("stage.accumulate", stage=self.stage):
-            self.d_stage = jax.tree.map(jnp.add, self.d_stage, dsp)
-            self.d_io = jax.tree.map(jnp.add, self.d_io, dio)
+            self._mb_grads[mb] = (dsp, dio)
 
     def finalize(self) -> "ActorStageProgram":
         """Fold stashed per-microbatch contributions in microbatch order.
@@ -349,10 +447,10 @@ class ActorStageProgram:
             y, loss = self.fns.forward(self.stage)(
                 self.sp_s, self.io, x, bm)
             self.residual[task.mb] = x
-            if self.deterministic_reduction:
-                self._mb_loss[task.mb] = loss
-            else:
-                with span("stage.accumulate", stage=self.stage):
+            with span("stage.accumulate", stage=self.stage):
+                if self.deterministic_reduction:
+                    self._mb_loss[task.mb] = loss
+                else:
                     self.loss_acc = self.loss_acc + loss
             self._g_dummy = jnp.zeros_like(y)
             return y
@@ -367,9 +465,14 @@ class ActorStageProgram:
                     return None  # nobody consumes stage 0's input gradient
                 return self.fns.backward_dx(self.stage)(
                     self.sp_s, self.io, x, g_in, bm)
-            dx, dsp, dio = self.fns.backward(self.stage)(
-                self.sp_s, self.io, x, g_in, bm)
-            self._add_grads(task.mb, dsp, dio)
+            args = (self.sp_s, self.io, x, g_in, bm)
+            if self.deterministic_reduction:
+                dx, dsp, dio = self.fns.backward(self.stage)(*args)
+                self._stash_grads(task.mb, dsp, dio)
+                return dx
+            dx, (self.d_stage, self.d_io) = self.fns.backward_into(
+                self.stage)((self.d_stage, self.d_io), *args)
+            self.folded_in_callable += 1
             return dx
         if task.kind == Kind.W:
             if not self.split_backward:
@@ -377,9 +480,14 @@ class ActorStageProgram:
                     f"{task!r} dispatched to a fused-backward stage program "
                     f"(construct ActorStageProgram with split_backward=True)")
             x, g_in = self.w_pending.pop(task.mb)
-            dsp, dio = self.fns.weight_grad(self.stage)(
-                self.sp_s, self.io, x, g_in, bm)
-            self._add_grads(task.mb, dsp, dio)
+            args = (self.sp_s, self.io, x, g_in, bm)
+            if self.deterministic_reduction:
+                self._stash_grads(
+                    task.mb, *self.fns.weight_grad(self.stage)(*args))
+            else:
+                self.d_stage, self.d_io = self.fns.weight_grad_into(
+                    self.stage)((self.d_stage, self.d_io), *args)
+                self.folded_in_callable += 1
             return None  # stage-local: no outgoing envelope
         raise ValueError(f"actor stage program cannot run {task!r}")
 
@@ -390,7 +498,8 @@ def warm_up(fns: StageFns, stage_params: list, io, batch: dict, *,
 
     Runs microbatch 0 through throwaway programs in pipeline order: F down
     the chain, then B (and W under split backward) back up — the same calls,
-    with the same shapes, that the first threaded step makes.  A cold compile
+    with the same shapes, that the first threaded step makes: the
+    accumulator build and the accumulating B/W of eager mode.  A cold compile
     at full width can outlast the actor runtime's starvation deadline, which
     would then report a deadlock that is only a compilation.
     """

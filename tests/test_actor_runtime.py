@@ -460,6 +460,200 @@ def test_threaded_real_model_matches_reference():
 
 
 # ---------------------------------------------------------------------------
+# Gradients folded into donated accumulators inside the B/W callable
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def real_model():
+    """The reduced deepseek-7b pipeline of the tests above: 2 stages, 4
+    microbatches of 2 rows, seq 16."""
+    import jax
+
+    from repro.configs import registry
+    from repro.models.build import build
+
+    S, M, mb_rows, seq = 2, 4, 2, 16
+    model = build(registry.reduced_config("deepseek-7b", num_layers=4),
+                  num_stages=S)
+    key = jax.random.key(0)
+    sp = model.init_stage_params(key)
+    io = model.init_io_params(jax.random.fold_in(key, 1))
+    batch = {k: jax.random.randint(jax.random.key(i), (M * mb_rows, seq), 0,
+                                   model.cfg.vocab_size)
+             for i, k in ((2, "tokens"), (3, "labels"))}
+    stages = [jax.tree.map(lambda x, s=s: x[s], sp) for s in range(S)]
+    return dict(model=model, S=S, M=M, mb_rows=mb_rows, seq=seq,
+                stages=stages, io=io, batch=batch)
+
+
+def _fns(m):
+    from repro.pipeline.stagefn import StageFnOptions, StageFns
+
+    tokens = m["M"] * m["mb_rows"] * m["seq"]
+    return StageFns(m["model"], StageFnOptions(
+        mb_rows=m["mb_rows"], seq_len=m["seq"], loss_scale=1.0 / tokens))
+
+
+def _run_step(m, fns, *, split, deterministic=False):
+    from repro.pipeline.stagefn import ActorStageProgram
+
+    programs = [ActorStageProgram(fns, s, m["stages"][s], m["io"],
+                                  m["batch"], split_backward=split,
+                                  deterministic_reduction=deterministic)
+                for s in range(m["S"])]
+    spec = PipelineSpec(m["S"], m["M"], split_backward=split)
+    r = ActorDriver(spec, None, ActorConfig(
+        mode="hint", hint=HintKind.BFW if split else HintKind.BF,
+        w_defer_cap=2 if split else 0,
+        deadlock_timeout=300.0)).run_threaded(list(programs))
+    assert set(r.end) == set(spec.tasks())
+    return [p.finalize() for p in programs]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("split", [False, True], ids=["bf", "bfw"])
+def test_folded_accumulators_match_the_deterministic_fold(real_model, split):
+    """Eager mode folds each microbatch's grads inside the callable; the
+    sums equal the deterministic mode's microbatch-order fold of the same
+    grads, leaf by leaf in each leaf's dtype, within float32 rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    m = real_model
+    fns = _fns(m)
+    eager = _run_step(m, fns, split=split)
+    det = _run_step(m, fns, split=split, deterministic=True)
+    for pe, pd in zip(eager, det):
+        assert pe.folded_in_callable == m["M"]
+        assert pd.folded_in_callable == 0
+        assert abs(pe.loss_sum - pd.loss_sum) <= 1e-6 * abs(pd.loss_sum)
+        for ge, gd in zip(jax.tree.leaves((pe.d_stage, pe.d_io)),
+                          jax.tree.leaves((pd.d_stage, pd.d_io))):
+            assert ge.dtype == gd.dtype and ge.shape == gd.shape
+            scale = float(jnp.max(jnp.abs(gd))) + 1e-30
+            assert float(jnp.max(jnp.abs(ge - gd))) <= 1e-5 * scale
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("split", [False, True], ids=["bf", "bfw"])
+def test_fold_donates_the_previous_accumulators(real_model, split):
+    """The B (fused) or W (split) call takes the accumulators donated: the
+    arrays the program held before the call are deleted after it."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.pipeline.stagefn import ActorStageProgram
+
+    m = real_model
+    fns = _fns(m)
+    prog = ActorStageProgram(fns, 0, m["stages"][0], m["io"], m["batch"],
+                             split_backward=split)
+    y = prog(Task(Kind.F, 0, 0), None)
+    before = jax.tree.leaves((prog.d_stage, prog.d_io))
+    prog(Task(Kind.B, 0, 0), jnp.ones_like(y))
+    if split:
+        assert not any(leaf.is_deleted() for leaf in before)
+        prog(Task(Kind.W, 0, 0), None)
+    assert all(leaf.is_deleted() for leaf in before)
+    assert prog.folded_in_callable == 1
+    after = jax.tree.leaves((prog.d_stage, prog.d_io))
+    assert [a.dtype for a in after] == [b.dtype for b in before]
+    assert sum(float(jnp.abs(a).sum()) for a in after) > 0
+
+
+@pytest.mark.parametrize("op,name", [("backward_into", "B"),
+                                     ("weight_grad_into", "W")])
+def test_accumulating_callable_keeps_its_module_name_and_aliases(
+        real_model, op, name):
+    """The accumulating callables lower as ``jit_stage{s}_B``/``_W`` and
+    alias every accumulator leaf to an output."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.pipeline.stagefn import microbatch, zero_accumulators
+
+    m = real_model
+    fns = _fns(m)
+    sp1, io = m["stages"][1], m["io"]
+    acc, _ = zero_accumulators(sp1, io)
+    x = jnp.zeros((m["mb_rows"], m["seq"], m["model"].cfg.d_model),
+                  m["model"].cfg.dtype)
+    text = getattr(fns, op)(1).lower(
+        acc, sp1, io, x, x, microbatch(m["batch"], 0, m["mb_rows"])).as_text()
+    assert f"module @jit_stage1_{name}" in text
+    assert text.count("tf.aliasing_output") == len(jax.tree.leaves(acc))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("split", [False, True], ids=["bf", "bfw"])
+def test_warm_up_leaves_nothing_to_compile(real_model, split):
+    """After ``warm_up`` a threaded step compiles nothing: every callable it
+    calls was compiled for the step's shapes, accumulators included."""
+    import jax
+
+    from repro.pipeline.stagefn import warm_up, zero_accumulators
+
+    m = real_model
+    fns = _fns(m)
+    warm_up(fns, m["stages"], m["io"], m["batch"], split_backward=split)
+    ops = ["forward", "backward_dx", "weight_grad_into"] if split else \
+        ["forward", "backward_into"]
+    jitted = [zero_accumulators] + [getattr(fns, op)(s) for op in ops
+                                    for s in range(m["S"])]
+    sizes = [f._cache_size() for f in jitted]
+    compiles = []
+
+    def on_duration(name, secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        programs = _run_step(m, fns, split=split)
+        jax.block_until_ready([(p.d_stage, p.d_io) for p in programs])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert [f._cache_size() for f in jitted] == sizes
+    assert compiles == []
+
+
+def test_embed_rows_backward_sums_each_token_in_float32():
+    """``embed_rows`` is the plain gather forward; its table gradient equals
+    the gather's in float32 and, in bfloat16 with a frequent token, lies
+    closer to the exact sum than the gather's one rounding per position."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.pipeline.stagefn import embed_rows
+
+    rng = np.random.default_rng(0)
+    vocab, d = 64, 16
+    tokens = jnp.asarray(np.where(rng.random((2, 256)) < 0.5, 3,
+                                  rng.integers(0, vocab, (2, 256))), jnp.int32)
+    ct = rng.standard_normal((2, 256, d)) * 0.1 + 1.0
+    table = jnp.asarray(rng.standard_normal((vocab, d)), jnp.float32)
+
+    def grads(lookup, dtype):
+        g = jnp.asarray(ct, dtype)
+        return np.asarray(jax.jit(jax.grad(lambda t: jnp.sum(
+            (lookup(t, tokens) * g).astype(jnp.float32))))(
+                table.astype(dtype)), np.float64)
+
+    gather = lambda t, tok: t[tok]  # noqa: E731
+    assert np.array_equal(np.asarray(embed_rows(table, tokens)),
+                          np.asarray(table[tokens]))
+    np.testing.assert_allclose(grads(embed_rows, jnp.float32),
+                               grads(gather, jnp.float32), rtol=1e-5,
+                               atol=1e-5)
+    exact = np.zeros((vocab, d))
+    np.add.at(exact, np.asarray(tokens).reshape(-1),
+              np.asarray(jnp.asarray(ct, jnp.bfloat16), np.float64)
+              .reshape(-1, d))
+    err = {f: np.abs(grads(f, jnp.bfloat16) - exact).max()
+           for f in (embed_rows, gather)}
+    assert err[embed_rows] < err[gather]
+
+
+# ---------------------------------------------------------------------------
 # Straggler monitor feedback from actor traces
 # ---------------------------------------------------------------------------
 def test_straggler_monitor_consumes_actor_result():
