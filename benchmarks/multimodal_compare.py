@@ -137,9 +137,8 @@ def real_threaded_dag(steps: int = 2) -> dict:
     import jax
 
     from repro.data.synthetic import multimodal_batch
-    from repro.multimodal import (
-        MultimodalStageFns, MultimodalStageProgram, multimodal_model)
-    from repro.multimodal.stagefn import MultimodalStageOptions
+    from repro.multimodal import MultimodalStageFns, multimodal_model
+    from repro.pipeline.stagefn import ActorStageProgram, StageFnOptions
     from repro.runtime.rrfp import ActorDriver
     from repro.runtime.rrfp.conformance import check_all
 
@@ -152,14 +151,14 @@ def real_threaded_dag(steps: int = 2) -> dict:
         cfg = model.cfg
         mm, rows = 4, 1
         params = model.init_stage_params(jax.random.key(0))
-        fns = MultimodalStageFns(model, MultimodalStageOptions(
-            mb_rows=rows, loss_scale=1.0 / (mm * rows * 16)))
+        fns = MultimodalStageFns(model, StageFnOptions(
+            mb_rows=rows, seq_len=16, loss_scale=1.0 / (mm * rows * 16)))
 
         def run(mode: str, step: int):
             batch = multimodal_batch(cfg, mm, rows, seed=0, step=step)
             progs = [
-                MultimodalStageProgram(fns, s, params[s], batch,
-                                       deterministic_reduction=True)
+                ActorStageProgram(fns, s, params[s], {}, batch,
+                                  deterministic_reduction=True)
                 for s in range(cfg.num_stages)
             ]
             spec = cfg.spec(mm)

@@ -197,10 +197,10 @@ def train_multimodal(args) -> list[float]:
     device.  Returns the loss history (thread) or makespan history (sim).
     """
     from repro.multimodal import (
-        MultimodalStageFns, MultimodalStageProgram, multimodal_config,
-        multimodal_dag_costs, multimodal_model)
+        MultimodalStageFns, multimodal_config, multimodal_dag_costs,
+        multimodal_model)
     from repro.multimodal.model import MULTIMODAL_ARCHS
-    from repro.multimodal.stagefn import MultimodalStageOptions
+    from repro.pipeline.stagefn import ActorStageProgram, StageFnOptions
     from repro.optim.adamw import AdamWConfig, make_host_update
     from repro.runtime.rrfp import ActorConfig, ActorDriver, parse_chaos
 
@@ -288,8 +288,8 @@ def train_multimodal(args) -> list[float]:
 
     params = model.init_stage_params(jax.random.key(args.seed))
     tokens = args.microbatches * args.mb_rows * args.seq
-    fns = MultimodalStageFns(model, MultimodalStageOptions(
-        mb_rows=args.mb_rows, loss_scale=1.0 / tokens))
+    fns = MultimodalStageFns(model, StageFnOptions(
+        mb_rows=args.mb_rows, seq_len=args.seq, loss_scale=1.0 / tokens))
     opt_cfg = AdamWConfig(lr=args.lr, warmup_steps=min(20, args.steps),
                           total_steps=max(args.steps, 1))
     mstate = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), params)
@@ -302,8 +302,8 @@ def train_multimodal(args) -> list[float]:
         batch = multimodal_batch(cfg, args.microbatches, args.mb_rows,
                                  seed=args.seed, step=step)
         programs = [
-            MultimodalStageProgram(fns, s, params[s], batch,
-                                   split_backward=split)
+            ActorStageProgram(fns, s, params[s], {}, batch,
+                              split_backward=split)
             for s in range(cfg.num_stages)
         ]
         t0 = time.time()
@@ -313,7 +313,7 @@ def train_multimodal(args) -> list[float]:
             dataclasses.replace(acfg, record_trace=True) if record_this
             else acfg)
         result = driver.run_threaded(list(programs))
-        grads = [p.d_params for p in programs]
+        grads = [p.d_stage for p in programs]
         params, mstate, vstate, lr = apply_update(
             params, grads, mstate, vstate, jnp.asarray(step, jnp.int32))
         loss = float(sum(p.loss_acc for p in programs)) / tokens

@@ -8,10 +8,11 @@ This package makes that regime executable end to end:
   model    -- branch+fusion DAG topology (encoder branch ∥ text frontend →
               fusion → LM chain) with real per-stage parameters built from
               ``models/layers.py``; bitwise padding-invariant encoder math
-  stagefn  -- per-(stage, op) jitted callables with shape bucketing
-              (compile cache bounded by bucket count) + the actor-runtime
-              ``work_fn`` adapter handling DAG fan-in/fan-out payloads,
-              BFW split backward and deterministic reduction
+  stagefn  -- the model hooks of ``pipeline.stagefn.StageFns`` for the
+              DAG: per-(stage, op) jitted callables with shape bucketing
+              (compile cache bounded by bucket count), run by the chain's
+              ``ActorStageProgram`` (fan-in/fan-out payloads, BFW split
+              backward, deterministic reduction)
   costs    -- DES cost models of the same topologies for the simulation
               substrate and the multimodal benchmark
 
@@ -25,17 +26,13 @@ from repro.multimodal.model import (
     multimodal_config,
     multimodal_model,
 )
-from repro.multimodal.stagefn import (
-    MultimodalStageFns,
-    MultimodalStageProgram,
-)
+from repro.multimodal.stagefn import MultimodalStageFns
 
 __all__ = [
     "MULTIMODAL_ARCHS",
     "MultimodalConfig",
     "MultimodalModel",
     "MultimodalStageFns",
-    "MultimodalStageProgram",
     "multimodal_config",
     "multimodal_dag_costs",
     "multimodal_model",

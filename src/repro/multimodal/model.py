@@ -324,6 +324,10 @@ class MultimodalModel:
             out.append(p)
         return out
 
+    @property
+    def num_stages(self) -> int:
+        return self.cfg.num_stages
+
     def param_count(self) -> int:
         key = jax.random.key(0)
         return sum(x.size for x in jax.tree.leaves(self.init_stage_params(key)))

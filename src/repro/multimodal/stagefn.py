@@ -1,346 +1,82 @@
-"""Jitted per-stage callables + actor-runtime adapter for the DAG pipeline.
+"""Jitted per-stage callables for the branch+fusion DAG pipeline.
 
-``MultimodalStageFns`` mirrors ``pipeline.stagefn.StageFns`` for the
-branch+fusion topology: one independently-jitted callable per (stage, op)
-that a host thread dispatches the moment the stage's message set arrives.
-Backward re-runs the stage forward under ``jax.grad`` of a scalarized
-objective (CE at the sink, <y, g_in> elsewhere); under BFW decomposition
-the backward splits into a dX-only B and a deferrable W, exactly like the
-linear-chain path.
+``MultimodalStageFns`` is ``pipeline.stagefn.StageFns`` with the model hooks
+of a ``MultimodalModel``; the builder, its module names
+(``jit_stage{s}_F``/``_B``/``_dX``/``_W``) and the actor-runtime adapter
+(``ActorStageProgram``) are the chain's.  The DAG's stages share no ``io``
+tree, so each stage program is built with ``io = {}``, and a stage's ``x``
+is the arrived payload:
+
+* None at the source stages: the first encoder stage (its embeddings are in
+  ``bm``) and the text stage (its tokens are in ``bm``);
+* ``{src_stage: activation}`` at the fusion stage, one entry per incoming
+  edge, so its backward emits one input gradient per branch;
+* the upstream activation everywhere else.
 
 **Shape bucketing.**  Encoder microbatches are variable-length; the batch
 builder pads each one up to a bucket from a small fixed set, so jax's jit
 cache retraces once per (stage, bucket) — the compile count is bounded by
 the bucket count, not the number of distinct lengths (asserted by
-``compile_cache_sizes`` in the bucketing tests).  The encoder math is
+``compile_cache_sizes`` in the bucketing tests).  The encoder forward is
 bitwise padding-invariant (see ``multimodal.model``), so bucketed and
-unbucketed execution produce identical loss and gradient bits.
-
-``MultimodalStageProgram`` adapts the callables to the actor runtime's
-``work_fn(task, payload)`` protocol, handling the DAG payload routing: the
-fusion stage's F consumes a ``{src_stage: payload}`` dict (one activation
-per incoming edge) and its B returns ``EdgePayloads`` (one input gradient
-per branch).
+unbucketed execution produce identical loss bits.  Their gradient bits
+currently differ (ROADMAP C1).
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Any
-
-import jax
-import jax.numpy as jnp
-
-from repro.core.taskgraph import Kind, Task
-from repro.multimodal.model import MultimodalModel
-from repro.runtime.rrfp.messages import EdgePayloads
+from repro.pipeline.stagefn import StageFns
 
 
-@dataclasses.dataclass(frozen=True)
-class MultimodalStageOptions:
-    mb_rows: int             # microbatch rows
-    loss_scale: float = 1.0  # applied to the CE objective
-
-
-class MultimodalStageFns:
+class MultimodalStageFns(StageFns):
     """Jitted forward/backward per stage of the branch+fusion pipeline."""
 
-    def __init__(self, model: MultimodalModel, opts: MultimodalStageOptions):
-        self.model = model
-        self.cfg = model.cfg
-        self.opts = opts
-        self._jit: dict[tuple[str, int], Any] = {}
-
-    # ---- shared scalarized objective -----------------------------------
-    def _objective(self, stage: int, p, inputs: tuple, g_in, labels):
-        """CE at the sink stage; <y, g_in> elsewhere.  ``inputs`` is the
-        stage's differentiable input tuple (see ``_forward_y``)."""
-        y = self._forward_y(stage, p, inputs)
-        if stage == self.cfg.num_stages - 1:
-            return self.model.loss_sum(p, y, labels) * self.opts.loss_scale
-        return jnp.sum(y.astype(jnp.float32) * g_in.astype(jnp.float32))
-
-    def _forward_y(self, stage: int, p, inputs: tuple):
-        role = self.cfg.role_of(stage)
+    def _stage_out(self, stage: int, sp_s, io, x, bm):
+        model, cfg = self.model, self.model.cfg
+        role = cfg.role_of(stage)
         if role == "encoder":
-            (x,), length = inputs[:-1], inputs[-1]
-            return self.model.encoder_forward(stage, p, x, length)
+            return model.encoder_forward(
+                stage, sp_s, bm["enc_embeds"] if stage == 0 else x,
+                bm["enc_lens"])
         if role == "text":
-            (tokens,) = inputs
-            return self.model.text_forward(p, tokens)
+            return model.text_forward(sp_s, bm["tokens"])
         if role == "fusion":
-            x_enc, x_txt, length = inputs
-            return self.model.fusion_forward(p, x_enc, length, x_txt)
-        (x,) = inputs
-        return self.model.lm_forward(p, x)
+            return model.fusion_forward(sp_s, x[cfg.enc_stages - 1],
+                                        bm["enc_lens"], x[cfg.text_stage])
+        return model.lm_forward(sp_s, x)
 
-    def _diff_inputs(self, stage: int, inputs: tuple) -> tuple:
-        """The subset of ``inputs`` that carries input gradients (drops the
-        integer length / token operands)."""
-        role = self.cfg.role_of(stage)
-        if role == "encoder":
-            return (inputs[0],)
+    def _stage_loss(self, stage: int, sp_s, io, y, bm):
+        return self.model.loss_sum(sp_s, y, bm["labels"])
+
+    def microbatch(self, batch: dict, mb: int, stage: int) -> dict:
+        """What stage ``stage`` reads of microbatch ``mb``, and no more: an
+        operand a stage does not read would still key its jit cache (the
+        encoder embeddings, by bucket)."""
+        cfg, r = self.model.cfg, self.opts.mb_rows
+        role = cfg.role_of(stage)
+        bm = {}
+        if stage == 0:
+            bm["enc_embeds"] = batch["enc_embeds"][mb]
+        if role in ("encoder", "fusion"):
+            bm["enc_lens"] = batch["enc_lens"][mb]
         if role == "text":
-            return ()
-        if role == "fusion":
-            return (inputs[0], inputs[1])
-        return (inputs[0],)
+            bm["tokens"] = batch["tokens"][mb * r:(mb + 1) * r]
+        if stage == cfg.num_stages - 1:
+            bm["labels"] = batch["labels"][mb * r:(mb + 1) * r]
+        return bm
 
-    def _rebuild(self, stage: int, diff: tuple, inputs: tuple) -> tuple:
-        role = self.cfg.role_of(stage)
-        if role == "encoder":
-            return (diff[0], inputs[-1])
-        if role == "text":
-            return inputs
-        if role == "fusion":
-            return (diff[0], diff[1], inputs[2])
-        return (diff[0],)
+    def stage_graph(self):
+        return self.model.cfg.stage_graph()
 
-    def _get(self, op: str, stage: int, builder):
-        key = (op, stage)
-        if key not in self._jit:
-            self._jit[key] = jax.jit(builder())
-        return self._jit[key]
-
-    # ---- public ops ----------------------------------------------------
-    def forward(self, stage: int):
-        """f(p, *inputs, labels) -> (y, loss_sum) — loss nonzero at sink."""
-        last = stage == self.cfg.num_stages - 1
-
-        def build():
-            def f(p, inputs, labels):
-                y = self._forward_y(stage, p, inputs)
-                # unscaled CE sum (loss_scale seeds the backward only, like
-                # the linear-chain StageFns)
-                loss = (self.model.loss_sum(p, y, labels)
-                        if last else jnp.zeros((), jnp.float32))
-                return y, loss
-            return f
-
-        return self._get("fwd", stage, build)
-
-    def backward(self, stage: int):
-        """Fused backward: f(p, inputs, g_in, labels) -> (dxs, dp)."""
-        def build():
-            def b(p, inputs, g_in, labels):
-                diff = self._diff_inputs(stage, inputs)
-
-                def obj(p_, diff_):
-                    return self._objective(
-                        stage, p_, self._rebuild(stage, diff_, inputs),
-                        g_in, labels)
-
-                dp, dxs = jax.grad(obj, argnums=(0, 1))(p, diff)
-                return dxs, dp
-            return b
-
-        return self._get("bwd", stage, build)
-
-    def backward_dx(self, stage: int):
-        """dX-only backward (the B task of the BFW decomposition)."""
-        def build():
-            def b(p, inputs, g_in, labels):
-                diff = self._diff_inputs(stage, inputs)
-
-                def obj(diff_):
-                    return self._objective(
-                        stage, p, self._rebuild(stage, diff_, inputs),
-                        g_in, labels)
-
-                return jax.grad(obj)(diff)
-            return b
-
-        return self._get("bwd_dx", stage, build)
-
-    def weight_grad(self, stage: int):
-        """Per-microbatch weight gradient (the deferrable W task)."""
-        def build():
-            def w(p, inputs, g_in, labels):
-                def obj(p_):
-                    return self._objective(stage, p_, inputs, g_in, labels)
-
-                return jax.grad(obj)(p)
-            return w
-
-        return self._get("wgrad", stage, build)
+    def warm_microbatches(self, batch: dict) -> list[int]:
+        """The first microbatch of each encoder bucket."""
+        first: dict[tuple, int] = {}
+        for mb, enc in enumerate(batch["enc_embeds"]):
+            first.setdefault(enc.shape, mb)
+        return sorted(first.values())
 
     # ---- bucketing observability ---------------------------------------
     def compile_cache_sizes(self) -> dict[tuple[str, int], int]:
         """Live jit-cache entry count per (op, stage): the number of
         distinct input shapes traced — bounded by the bucket count for the
         variable-length encoder/fusion stages."""
-        return {k: f._cache_size() for k, f in self._jit.items()}
-
-
-# ---------------------------------------------------------------------------
-# actor-runtime adapter
-# ---------------------------------------------------------------------------
-class MultimodalStageProgram:
-    """``work_fn(task, payload)`` for one DAG stage driving real callables.
-
-    Payload protocol (set by the runtime's fan-in/fan-out rules):
-
-    * single-predecessor F tasks receive the upstream activation array;
-      the fusion stage's F receives ``{src_stage: activation}``;
-    * the fusion stage's B returns ``EdgePayloads`` with one input
-      gradient per incoming branch; every other B returns its dx (or None
-      at branch roots, whose input gradient nobody consumes);
-    * W is stage-local and returns None.
-
-    With ``deterministic_reduction=True`` per-microbatch loss/grad
-    contributions are stashed and :meth:`finalize` folds them in microbatch
-    order, making the final bits independent of the runtime's dispatch
-    order (the conformance-parity property).
-    """
-
-    def __init__(self, fns: MultimodalStageFns, stage: int, params,
-                 batch: dict, *, split_backward: bool = False,
-                 deterministic_reduction: bool = False):
-        self.fns = fns
-        self.cfg = fns.cfg
-        self.stage = stage
-        self.params = params
-        self.batch = batch
-        self.split_backward = split_backward
-        self.deterministic_reduction = deterministic_reduction
-        self.residual: dict[int, tuple] = {}   # mb -> stage input tuple
-        #: BFW: mb -> (inputs, g_in) held from B-time until W fires
-        self.w_pending: dict[int, tuple] = {}
-        self.w_high_water = 0
-        self.d_params = jax.tree.map(jnp.zeros_like, params)
-        self.loss_acc = jnp.zeros((), jnp.float32)
-        self._mb_loss: dict[int, Any] = {}
-        self._mb_grads: dict[int, Any] = {}
-        self._loss_folded: int | None = None
-        self._grads_folded: int | None = None
-
-    # ---- batch slicing -------------------------------------------------
-    def _mb_tokens(self, mb: int):
-        r = self.fns.opts.mb_rows
-        return self.batch["tokens"][mb * r:(mb + 1) * r]
-
-    def _mb_labels(self, mb: int):
-        r = self.fns.opts.mb_rows
-        return self.batch["labels"][mb * r:(mb + 1) * r]
-
-    def _mb_enc(self, mb: int):
-        return self.batch["enc_embeds"][mb]
-
-    def _mb_len(self, mb: int):
-        return jnp.asarray(self.batch["enc_lens"][mb], jnp.int32)
-
-    # ---- inputs per role -----------------------------------------------
-    def _f_inputs(self, mb: int, payload) -> tuple:
-        role = self.cfg.role_of(self.stage)
-        if role == "encoder":
-            x = payload if self.stage > 0 else jnp.asarray(self._mb_enc(mb))
-            return (x, self._mb_len(mb))
-        if role == "text":
-            return (jnp.asarray(self._mb_tokens(mb)),)
-        if role == "fusion":
-            enc_src = self.cfg.enc_stages - 1
-            return (payload[enc_src], payload[self.cfg.text_stage],
-                    self._mb_len(mb))
-        return (payload,)
-
-    # ---- accumulation ---------------------------------------------------
-    def _add_grads(self, mb: int, dp) -> None:
-        if self.deterministic_reduction:
-            self._mb_grads[mb] = dp
-            return
-        self.d_params = jax.tree.map(jnp.add, self.d_params, dp)
-
-    def finalize(self) -> "MultimodalStageProgram":
-        """Fold stashed contributions in microbatch order (idempotent; a
-        fold below an already-folded microbatch raises — see
-        ``ActorStageProgram.finalize`` for why mid-run folds are unsafe)."""
-        def fold_guard(kind: str, folded: int | None, keys) -> int | None:
-            if folded is not None and keys and min(keys) < folded:
-                raise RuntimeError(
-                    f"stage {self.stage}: deterministic {kind} fold of "
-                    f"microbatch {min(keys)} after microbatch {folded} was "
-                    f"already folded — finalize()/loss_sum was read mid-run")
-            return max(keys, default=folded) if keys else folded
-
-        self._loss_folded = fold_guard(
-            "loss", self._loss_folded, list(self._mb_loss))
-        for mb in sorted(self._mb_loss):
-            self.loss_acc = self.loss_acc + self._mb_loss[mb]
-        self._mb_loss.clear()
-        self._grads_folded = fold_guard(
-            "grad", self._grads_folded, list(self._mb_grads))
-        for mb in sorted(self._mb_grads):
-            self.d_params = jax.tree.map(
-                jnp.add, self.d_params, self._mb_grads[mb])
-        self._mb_grads.clear()
-        return self
-
-    @property
-    def loss_sum(self) -> float:
-        """Materialized loss total (forces one device sync per read)."""
-        self.finalize()
-        return float(self.loss_acc)
-
-    def w_outstanding(self) -> int:
-        return len(self.w_pending)
-
-    # ---- work_fn ---------------------------------------------------------
-    def __call__(self, task: Task, payload: Any) -> Any:
-        cfg, fns = self.cfg, self.fns
-        last = self.stage == cfg.num_stages - 1
-        labels = jnp.asarray(self._mb_labels(task.mb)) if last else \
-            jnp.zeros((1, 1), jnp.int32)
-        if task.kind == Kind.F:
-            inputs = self._f_inputs(task.mb, payload)
-            y, loss = fns.forward(self.stage)(self.params, inputs, labels)
-            self.residual[task.mb] = inputs
-            if last:
-                if self.deterministic_reduction:
-                    self._mb_loss[task.mb] = loss
-                else:
-                    self.loss_acc = self.loss_acc + loss
-            return y
-        if task.kind == Kind.B:
-            inputs = self.residual.pop(task.mb)
-            g_in = payload if payload is not None else \
-                jnp.zeros((1,), jnp.float32)
-            if self.split_backward:
-                self.w_pending[task.mb] = (inputs, g_in)
-                self.w_high_water = max(self.w_high_water,
-                                        len(self.w_pending))
-                return self._emit_dx(task, inputs, g_in, labels)
-            dxs, dp = fns.backward(self.stage)(
-                self.params, inputs, g_in, labels)
-            self._add_grads(task.mb, dp)
-            return self._route_dx(dxs)
-        if task.kind == Kind.W:
-            if not self.split_backward:
-                raise ValueError(
-                    f"{task!r} dispatched to a fused-backward stage program")
-            inputs, g_in = self.w_pending.pop(task.mb)
-            dp = fns.weight_grad(self.stage)(
-                self.params, inputs, g_in, labels)
-            self._add_grads(task.mb, dp)
-            return None
-        raise ValueError(f"multimodal stage program cannot run {task!r}")
-
-    def _emit_dx(self, task: Task, inputs, g_in, labels):
-        """Split-backward B: dX only (skipped at branch roots — nobody
-        consumes a source stage's input gradient)."""
-        role = self.cfg.role_of(self.stage)
-        if role == "text" or (role == "encoder" and self.stage == 0):
-            return None
-        dxs = self.fns.backward_dx(self.stage)(
-            self.params, inputs, g_in, labels)
-        return self._route_dx(dxs)
-
-    def _route_dx(self, dxs: tuple):
-        """Map the dX tuple onto the outgoing-edge payload protocol."""
-        role = self.cfg.role_of(self.stage)
-        if role == "fusion":
-            return EdgePayloads({
-                self.cfg.enc_stages - 1: dxs[0],
-                self.cfg.text_stage: dxs[1],
-            })
-        if role == "text" or not dxs:
-            return None
-        return dxs[0]
+        return {k: f._cache_size() for k, f in self._jitted.items()}

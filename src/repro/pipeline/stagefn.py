@@ -7,8 +7,12 @@ the opposite factoring: one independently-callable, jitted function per
 arrives.  This module provides that factoring for single-process meshes
 (CPU or multi-device single-host), sharing the executor's loss
 (:func:`chunked_ce_sum`) and its remat-based backward recipe: B re-runs the
-stage forward under ``jax.grad`` of a scalarized objective (CE at the last
-stage, <y, g_in> elsewhere).
+stage forward under ``jax.grad`` of a scalarized objective (the loss at the
+last stage, <y, g_in> elsewhere).
+
+One builder serves chains and DAGs.  ``StageFns`` implements the model hooks
+for an ``ArchModel`` chain; ``multimodal.stagefn`` overrides them for the
+branch+fusion DAG, whose stages share no ``io`` tree (``io = {}``).
 
 ``ActorStageProgram`` adapts the callables to the actor runtime's
 ``work_fn(task, payload)`` protocol: it holds the stage's residual store
@@ -20,15 +24,17 @@ each microbatch's gradients into them inside the same jitted program.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.taskgraph import Kind, Task
+from repro.core.taskgraph import Kind, StageGraph, Task
 from repro.models.build import ArchModel
 from repro.models.layers import rmsnorm
 from repro.obs.spans import span
+from repro.runtime.rrfp.messages import EdgePayloads, payload_for_edge
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,10 +154,16 @@ zero_accumulators = _jit(_zero_accumulators, "stage_init")
 class StageFns:
     """Jitted forward/backward per stage of a single-process pipeline.
 
+    Every stage has one calling convention.  ``x`` is the stage's
+    differentiable input, the arrived payload: an array on a single-edge
+    stage, a ``{src_stage: array}`` dict at a fan-in stage, None at a source
+    stage (chain stage 0, which embeds ``bm``'s tokens).  Everything else a
+    task reads is in ``bm``, the microbatch's slice of the batch.
+
     ``forward(s)(sp_s, io, x, bm) -> (y, loss_sum)`` — loss_sum nonzero only
     at the last stage.  ``backward(s)(sp_s, io, x, g_in, bm) ->
     (dx, d_stage, d_io)`` — g_in ignored at the last stage (the loss is the
-    objective there).
+    objective there); dx has ``x``'s structure.
 
     Under BFW decomposition the fused backward splits into two jitted
     callables over the *same* scalarized objective:
@@ -172,21 +184,22 @@ class StageFns:
     ``jit_stage{s}_W`` (an accumulating form under its plain form's name);
     inside, ops carry the scopes ``embed``, ``layers`` and ``ce_loss``, and a
     backward's re-run forward sits under ``recompute``.
+
+    Model hooks, overridden for another model: ``_stage_out``,
+    ``_stage_loss`` (unscaled, last stage), ``microbatch`` (a stage's
+    ``bm``), and for ``warm_up`` ``stage_graph`` and ``warm_microbatches``.
     """
 
-    def __init__(self, model: ArchModel, opts: StageFnOptions):
+    def __init__(self, model, opts: StageFnOptions):
         self.model = model
         self.opts = opts
-        cfg = model.cfg
-        self.ce_chunk = default_ce_chunk(cfg, opts.ce_chunk)
-        self._fwd: dict[int, Any] = {}
-        self._bwd: dict[int, Any] = {}
-        self._bwd_dx: dict[int, Any] = {}
-        self._wgrad: dict[int, Any] = {}
-        self._bwd_into: dict[int, Any] = {}
-        self._wgrad_into: dict[int, Any] = {}
+        self._jitted: dict[tuple[str, int], Any] = {}  # by (op, stage)
 
-    # ---- helpers -------------------------------------------------------
+    @functools.cached_property
+    def ce_chunk(self) -> int:
+        return default_ce_chunk(self.model.cfg, self.opts.ce_chunk)
+
+    # ---- model hooks: an ArchModel chain -------------------------------
     def _aux(self, bm: dict) -> dict:
         seq = self.opts.seq_len
         a: dict[str, Any] = {
@@ -216,13 +229,29 @@ class StageFns:
             return model.stage_forward(sp_s, io, x, self._aux(bm),
                                        model.rows(stage))
 
+    def _stage_loss(self, stage: int, sp_s, io, y, bm):
+        """Unscaled token cross-entropy sum of the last stage's output."""
+        return chunked_ce_sum(self.model, io, y, bm["labels"], self.ce_chunk)
+
+    def microbatch(self, batch: dict, mb: int, stage: int) -> dict:
+        """Stage ``stage``'s ``bm`` for microbatch ``mb``."""
+        return microbatch(batch, mb, self.opts.mb_rows)
+
+    def stage_graph(self) -> StageGraph:
+        """The stages' forward edges (``warm_up`` routes payloads on them)."""
+        return StageGraph.linear(self.model.num_stages)
+
+    def warm_microbatches(self, batch: dict) -> list[int]:
+        """One microbatch of each set of shapes in ``batch``: all alike."""
+        return [0]
+
+    # ---- objective -----------------------------------------------------
     def _objective(self, stage: int, sp_s, io, x, g_in, bm):
-        model = self.model
         with jax.named_scope("recompute"):
             y = self._stage_out(stage, sp_s, io, x, bm)
-            if stage == model.num_stages - 1:
-                return chunked_ce_sum(model, io, y, bm["labels"],
-                                      self.ce_chunk) * self.opts.loss_scale
+            if stage == self.model.num_stages - 1:
+                return self._stage_loss(stage, sp_s, io, y,
+                                        bm) * self.opts.loss_scale
             return jnp.sum(y.astype(jnp.float32) * g_in.astype(jnp.float32))
 
     def _fused_grads(self, stage: int, sp_s, io, x, g_in, bm):
@@ -239,73 +268,65 @@ class StageFns:
             lambda sp_, io_: self._objective(stage, sp_, io_, x, g_in, bm),
             argnums=(0, 1))(sp_s, io)
 
+    def _once(self, op: str, stage: int, suffix: str, fn, **jit_kwargs):
+        """``fn`` jitted as ``jit_stage{stage}_{suffix}``, once per op."""
+        key = (op, stage)
+        if key not in self._jitted:
+            self._jitted[key] = _jit(fn, f"stage{stage}_{suffix}",
+                                     **jit_kwargs)
+        return self._jitted[key]
+
     # ---- public --------------------------------------------------------
     def forward(self, stage: int):
-        if stage not in self._fwd:
-            model = self.model
-            last = stage == model.num_stages - 1
+        last = stage == self.model.num_stages - 1
 
-            def f(sp_s, io, x, bm):
-                y = self._stage_out(stage, sp_s, io, x, bm)
-                loss = (chunked_ce_sum(model, io, y, bm["labels"],
-                                       self.ce_chunk)
-                        if last else jnp.zeros((), jnp.float32))
-                return y, loss
+        def f(sp_s, io, x, bm):
+            y = self._stage_out(stage, sp_s, io, x, bm)
+            loss = (self._stage_loss(stage, sp_s, io, y, bm)
+                    if last else jnp.zeros((), jnp.float32))
+            return y, loss
 
-            self._fwd[stage] = _jit(f, f"stage{stage}_F")
-        return self._fwd[stage]
+        return self._once("forward", stage, "F", f)
 
     def backward(self, stage: int):
-        if stage not in self._bwd:
-            def b(sp_s, io, x, g_in, bm):
-                return self._fused_grads(stage, sp_s, io, x, g_in, bm)
+        def b(sp_s, io, x, g_in, bm):
+            return self._fused_grads(stage, sp_s, io, x, g_in, bm)
 
-            self._bwd[stage] = _jit(b, f"stage{stage}_B")
-        return self._bwd[stage]
+        return self._once("backward", stage, "B", b)
 
     def backward_into(self, stage: int):
         """Fused backward that folds its grads into the donated ``acc``."""
-        if stage not in self._bwd_into:
-            def b(acc, sp_s, io, x, g_in, bm):
-                dx, dsp, dio = self._fused_grads(stage, sp_s, io, x, g_in, bm)
-                return dx, _fold(acc, (dsp, dio))
+        def b(acc, sp_s, io, x, g_in, bm):
+            dx, dsp, dio = self._fused_grads(stage, sp_s, io, x, g_in, bm)
+            return dx, _fold(acc, (dsp, dio))
 
-            self._bwd_into[stage] = _jit(b, f"stage{stage}_B",
-                                         donate_argnums=0)
-        return self._bwd_into[stage]
+        return self._once("backward_into", stage, "B", b, donate_argnums=0)
 
     def backward_dx(self, stage: int):
         """dX-only backward (the B task of the BFW decomposition)."""
-        if stage not in self._bwd_dx:
-            def b_dx(sp_s, io, x, g_in, bm):
-                (dx,) = jax.grad(
-                    lambda x_: self._objective(
-                        stage, sp_s, io, x_, g_in, bm),
-                    argnums=(0,))(x)
-                return dx
+        def b_dx(sp_s, io, x, g_in, bm):
+            (dx,) = jax.grad(
+                lambda x_: self._objective(stage, sp_s, io, x_, g_in, bm),
+                argnums=(0,))(x)
+            return dx
 
-            self._bwd_dx[stage] = _jit(b_dx, f"stage{stage}_dX")
-        return self._bwd_dx[stage]
+        return self._once("backward_dx", stage, "dX", b_dx)
 
     def weight_grad(self, stage: int):
         """Per-microbatch weight gradient (the deferrable W task)."""
-        if stage not in self._wgrad:
-            def w(sp_s, io, x, g_in, bm):
-                return self._weight_grads(stage, sp_s, io, x, g_in, bm)
+        def w(sp_s, io, x, g_in, bm):
+            return self._weight_grads(stage, sp_s, io, x, g_in, bm)
 
-            self._wgrad[stage] = _jit(w, f"stage{stage}_W")
-        return self._wgrad[stage]
+        return self._once("weight_grad", stage, "W", w)
 
     def weight_grad_into(self, stage: int):
         """Weight gradient folded into the donated ``acc`` (the W task)."""
-        if stage not in self._wgrad_into:
-            def w(acc, sp_s, io, x, g_in, bm):
-                return _fold(acc, self._weight_grads(stage, sp_s, io, x,
-                                                     g_in, bm))
+        def w(acc, sp_s, io, x, g_in, bm):
+            return _fold(acc, self._weight_grads(stage, sp_s, io, x, g_in,
+                                                 bm))
 
-            self._wgrad_into[stage] = _jit(w, f"stage{stage}_W",
-                                           donate_argnums=0)
-        return self._wgrad_into[stage]
+        return self._once("weight_grad_into", stage, "W", w,
+                          donate_argnums=0)
 
 
 def microbatch(batch: dict, mb: int, mb_rows: int) -> dict:
@@ -323,20 +344,27 @@ def microbatch(batch: dict, mb: int, mb_rows: int) -> dict:
 # ---------------------------------------------------------------------------
 # actor-runtime adapter
 # ---------------------------------------------------------------------------
+def _edge_payload(dx):
+    """A fan-in stage's ``{src_stage: dx}`` goes out one entry per edge."""
+    return EdgePayloads(dx) if isinstance(dx, dict) else dx
+
+
 class ActorStageProgram:
     """``work_fn(task, payload)`` for one stage actor driving real callables.
 
-    F: consume the upstream activation payload (None at stage 0), run the
-    jitted forward, stash the stage input for remat-backward, emit y.
+    F: consume the upstream activation payload (None at a source stage, a
+    ``{src_stage: activation}`` dict at a fan-in stage), run the jitted
+    forward, stash the stage input for remat-backward, emit y.
     B (fused): consume the downstream gradient payload (None at the last
     stage), re-run forward under grad, fold the parameter grads into the
-    accumulators inside the same jitted call, emit dx.
+    accumulators inside the same jitted call, emit dx (``EdgePayloads`` at
+    a fan-in stage, one per incoming edge; nothing at a source stage).
 
     With ``split_backward=True`` (the BFW decomposition):
 
     B: run the dX-only backward, stash the (x, g_in) pair for the W task,
-    emit dx.  Stage 0 skips the dX computation entirely — no stage consumes
-    its input gradient.
+    emit dx.  A source stage skips the dX computation entirely — no stage
+    consumes its input gradient.
     W: consume the stashed pair, run the weight-grad callable, folding into
     ``d_stage``/``d_io`` as B does.  W emits no payload: its result is
     stage-local (``PipelineSpec.message_successor`` is None for W, so no
@@ -441,9 +469,9 @@ class ActorStageProgram:
         return len(self.w_pending)
 
     def __call__(self, task: Task, payload: Any) -> Any:
-        bm = microbatch(self.batch, task.mb, self.fns.opts.mb_rows)
+        bm = self.fns.microbatch(self.batch, task.mb, self.stage)
         if task.kind == Kind.F:
-            x = payload  # None at stage 0 (embedded inside the callable)
+            x = payload  # None at a source stage (its input is in bm)
             y, loss = self.fns.forward(self.stage)(
                 self.sp_s, self.io, x, bm)
             self.residual[task.mb] = x
@@ -461,19 +489,19 @@ class ActorStageProgram:
                 self.w_pending[task.mb] = (x, g_in)
                 self.w_high_water = max(self.w_high_water,
                                         len(self.w_pending))
-                if self.stage == 0:
-                    return None  # nobody consumes stage 0's input gradient
-                return self.fns.backward_dx(self.stage)(
-                    self.sp_s, self.io, x, g_in, bm)
+                if x is None:
+                    return None  # nobody consumes a source's input gradient
+                return _edge_payload(self.fns.backward_dx(self.stage)(
+                    self.sp_s, self.io, x, g_in, bm))
             args = (self.sp_s, self.io, x, g_in, bm)
             if self.deterministic_reduction:
                 dx, dsp, dio = self.fns.backward(self.stage)(*args)
                 self._stash_grads(task.mb, dsp, dio)
-                return dx
+                return _edge_payload(dx)
             dx, (self.d_stage, self.d_io) = self.fns.backward_into(
                 self.stage)((self.d_stage, self.d_io), *args)
             self.folded_in_callable += 1
-            return dx
+            return _edge_payload(dx)
         if task.kind == Kind.W:
             if not self.split_backward:
                 raise ValueError(
@@ -496,22 +524,34 @@ def warm_up(fns: StageFns, stage_params: list, io, batch: dict, *,
             split_backward: bool = False) -> None:
     """Compile every stage's callables before threads dispatch them.
 
-    Runs microbatch 0 through throwaway programs in pipeline order: F down
-    the chain, then B (and W under split backward) back up — the same calls,
-    with the same shapes, that the first threaded step makes: the
-    accumulator build and the accumulating B/W of eager mode.  A cold compile
-    at full width can outlast the actor runtime's starvation deadline, which
-    would then report a deadlock that is only a compilation.
+    Runs each of ``fns.warm_microbatches(batch)`` (microbatch 0 of a chain)
+    through throwaway programs in pipeline order: F down the stage graph,
+    then B (and W under split backward) back up, each stage handed its
+    payload as the runtime's mailbox would — the same calls, with the same
+    shapes, that the first threaded step makes: the accumulator build and
+    the accumulating B/W of eager mode.  A cold compile at full width can
+    outlast the actor runtime's starvation deadline, which would then
+    report a deadlock that is only a compilation.
     """
+    graph = fns.stage_graph()
+    order = graph.topological_order()
     progs = [ActorStageProgram(fns, s, sp_s, io, batch,
                                split_backward=split_backward)
              for s, sp_s in enumerate(stage_params)]
-    payload = None
-    for s, prog in enumerate(progs):
-        payload = prog(Task(Kind.F, s, 0), payload)
-    payload = None
-    for s in reversed(range(len(progs))):
-        payload = progs[s](Task(Kind.B, s, 0), payload)
-        if split_backward:
-            progs[s](Task(Kind.W, s, 0), None)
+
+    def arrived(outs: dict, srcs, dst: int):
+        by_src = {src: payload_for_edge(outs[src], dst) for src in srcs}
+        return by_src if len(by_src) > 1 else next(iter(by_src.values()),
+                                                   None)
+
+    for mb in fns.warm_microbatches(batch):
+        ys, dxs = {}, {}
+        for s in order:
+            ys[s] = progs[s](Task(Kind.F, s, mb),
+                             arrived(ys, graph.preds(s), s))
+        for s in reversed(order):
+            dxs[s] = progs[s](Task(Kind.B, s, mb),
+                              arrived(dxs, graph.succs(s), s))
+            if split_backward:
+                progs[s](Task(Kind.W, s, mb), None)
     jax.block_until_ready([(p.d_stage, p.d_io) for p in progs])

@@ -462,14 +462,15 @@ def test_threaded_real_model_matches_reference():
 # ---------------------------------------------------------------------------
 # Gradients folded into donated accumulators inside the B/W callable
 # ---------------------------------------------------------------------------
-@pytest.fixture(scope="module")
-def real_model():
+def _chain():
     """The reduced deepseek-7b pipeline of the tests above: 2 stages, 4
     microbatches of 2 rows, seq 16."""
     import jax
+    import jax.numpy as jnp
 
     from repro.configs import registry
     from repro.models.build import build
+    from repro.pipeline.stagefn import StageFns
 
     S, M, mb_rows, seq = 2, 4, 2, 16
     model = build(registry.reduced_config("deepseek-7b", num_layers=4),
@@ -481,15 +482,46 @@ def real_model():
                                    model.cfg.vocab_size)
              for i, k in ((2, "tokens"), (3, "labels"))}
     stages = [jax.tree.map(lambda x, s=s: x[s], sp) for s in range(S)]
+    x1 = jnp.zeros((mb_rows, seq, model.cfg.d_model), model.cfg.dtype)
     return dict(model=model, S=S, M=M, mb_rows=mb_rows, seq=seq,
-                stages=stages, io=io, batch=batch)
+                stages=stages, io=io, batch=batch, Fns=StageFns, x1=x1,
+                spec=lambda split: PipelineSpec(S, M, split_backward=split))
+
+
+def _dag():
+    """A tiny branch+fusion DAG: 2 encoder stages and a text stage into the
+    fusion stage, then 1 LM stage; 4 microbatches of 2 rows, seq 16."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.data.synthetic import multimodal_batch
+    from repro.multimodal import MultimodalStageFns, multimodal_model
+
+    M, mb_rows, seq = 4, 2, 16
+    model = multimodal_model(
+        "qwen2-vl-2b", enc_stages=2, lm_stages=2, enc_layers_per_stage=1,
+        lm_layers_per_stage=1, text_seq=seq, fusion_slots=4,
+        mean_enc_tokens=14, buckets=(8, 16, 24))
+    cfg = model.cfg
+    batch = multimodal_batch(cfg, M, mb_rows, seed=0, step=0)
+    return dict(model=model, S=cfg.num_stages, M=M, mb_rows=mb_rows,
+                seq=seq, stages=model.init_stage_params(jax.random.key(0)),
+                io={}, batch=batch, Fns=MultimodalStageFns,
+                x1=jnp.zeros_like(batch["enc_embeds"][0]),
+                spec=lambda split: cfg.spec(M, split_backward=split))
+
+
+@pytest.fixture(scope="module", params=["chain", "dag"])
+def real_model(request):
+    """A chain and a DAG pipeline; ``x1`` is an input of stage 1."""
+    return {"chain": _chain, "dag": _dag}[request.param]()
 
 
 def _fns(m):
-    from repro.pipeline.stagefn import StageFnOptions, StageFns
+    from repro.pipeline.stagefn import StageFnOptions
 
     tokens = m["M"] * m["mb_rows"] * m["seq"]
-    return StageFns(m["model"], StageFnOptions(
+    return m["Fns"](m["model"], StageFnOptions(
         mb_rows=m["mb_rows"], seq_len=m["seq"], loss_scale=1.0 / tokens))
 
 
@@ -500,7 +532,7 @@ def _run_step(m, fns, *, split, deterministic=False):
                                   m["batch"], split_backward=split,
                                   deterministic_reduction=deterministic)
                 for s in range(m["S"])]
-    spec = PipelineSpec(m["S"], m["M"], split_backward=split)
+    spec = m["spec"](split)
     r = ActorDriver(spec, None, ActorConfig(
         mode="hint", hint=HintKind.BFW if split else HintKind.BF,
         w_defer_cap=2 if split else 0,
@@ -567,18 +599,15 @@ def test_accumulating_callable_keeps_its_module_name_and_aliases(
     """The accumulating callables lower as ``jit_stage{s}_B``/``_W`` and
     alias every accumulator leaf to an output."""
     import jax
-    import jax.numpy as jnp
 
-    from repro.pipeline.stagefn import microbatch, zero_accumulators
+    from repro.pipeline.stagefn import zero_accumulators
 
     m = real_model
     fns = _fns(m)
-    sp1, io = m["stages"][1], m["io"]
+    sp1, io, x = m["stages"][1], m["io"], m["x1"]
     acc, _ = zero_accumulators(sp1, io)
-    x = jnp.zeros((m["mb_rows"], m["seq"], m["model"].cfg.d_model),
-                  m["model"].cfg.dtype)
     text = getattr(fns, op)(1).lower(
-        acc, sp1, io, x, x, microbatch(m["batch"], 0, m["mb_rows"])).as_text()
+        acc, sp1, io, x, x, fns.microbatch(m["batch"], 0, 1)).as_text()
     assert f"module @jit_stage1_{name}" in text
     assert text.count("tf.aliasing_output") == len(jax.tree.leaves(acc))
 

@@ -25,12 +25,8 @@ from repro.core import HintKind
 from repro.core.taskgraph import Kind, Task
 from repro.data.lengths import bucket_for, length_skew, sample_token_lengths
 from repro.data.synthetic import multimodal_batch
-from repro.multimodal import (
-    MultimodalStageFns,
-    MultimodalStageProgram,
-    multimodal_model,
-)
-from repro.multimodal.stagefn import MultimodalStageOptions
+from repro.multimodal import MultimodalStageFns, multimodal_model
+from repro.pipeline.stagefn import ActorStageProgram, StageFnOptions
 from repro.runtime.rrfp import ActorConfig, ActorDriver, ChaosConfig
 
 M, ROWS, SEQ = 5, 2, 16
@@ -44,8 +40,8 @@ def tiny():
         lm_layers_per_stage=1, text_seq=SEQ, fusion_slots=4,
         mean_enc_tokens=14, buckets=BUCKETS)
     params = model.init_stage_params(jax.random.key(0))
-    fns = MultimodalStageFns(model, MultimodalStageOptions(
-        mb_rows=ROWS, loss_scale=1.0 / (M * ROWS * SEQ)))
+    fns = MultimodalStageFns(model, StageFnOptions(
+        mb_rows=ROWS, seq_len=SEQ, loss_scale=1.0 / (M * ROWS * SEQ)))
     return model, params, fns
 
 
@@ -55,9 +51,9 @@ def run_step(model, params, fns, *, bucketing=True, split=False, cap=0,
     batch = multimodal_batch(cfg, M, ROWS, seed=0, step=step,
                              bucketing=bucketing)
     programs = [
-        MultimodalStageProgram(fns, s, params[s], batch,
-                               split_backward=split,
-                               deterministic_reduction=deterministic)
+        ActorStageProgram(fns, s, params[s], {}, batch,
+                          split_backward=split,
+                          deterministic_reduction=deterministic)
         for s in range(cfg.num_stages)
     ]
     spec = cfg.spec(M, split_backward=split)
@@ -74,7 +70,7 @@ def run_step(model, params, fns, *, bucketing=True, split=False, cap=0,
 def loss_grad_bits(programs):
     loss = np.asarray(sum(p.loss_acc for p in programs)).tobytes()
     grads = b"".join(np.asarray(g).tobytes()
-                     for p in programs for g in jax.tree.leaves(p.d_params))
+                     for p in programs for g in jax.tree.leaves(p.d_stage))
     return loss, grads
 
 
@@ -135,7 +131,7 @@ class TestShapeBucketing:
             batch = multimodal_batch(cfg, M, ROWS, seed=11, step=step)
             seen |= {e.shape[1] for e in batch["enc_embeds"]}
             programs = [
-                MultimodalStageProgram(fns, s, params[s], batch)
+                ActorStageProgram(fns, s, params[s], {}, batch)
                 for s in range(cfg.num_stages)
             ]
             acfg = ActorConfig(mode="hint", hint=HintKind.BF,
@@ -152,22 +148,22 @@ class TestShapeBucketing:
         lengths (what bucketing is bounding)."""
         model, params, _ = tiny
         cfg = model.cfg
-        fns = MultimodalStageFns(model, MultimodalStageOptions(
-            mb_rows=ROWS, loss_scale=1.0 / (M * ROWS * SEQ)))
+        fns = MultimodalStageFns(model, StageFnOptions(
+            mb_rows=ROWS, seq_len=SEQ, loss_scale=1.0 / (M * ROWS * SEQ)))
         lengths = set()
         for step in range(4):
             batch = multimodal_batch(cfg, M, ROWS, seed=11, step=step,
                                      bucketing=False)
             lengths |= {e.shape[1] for e in batch["enc_embeds"]}
             programs = [
-                MultimodalStageProgram(fns, s, params[s], batch)
+                ActorStageProgram(fns, s, params[s], {}, batch)
                 for s in range(cfg.num_stages)
             ]
             acfg = ActorConfig(mode="hint", hint=HintKind.BF,
                                deadlock_timeout=120.0)
             ActorDriver(cfg.spec(M), None, acfg).run_threaded(list(programs))
         sizes = fns.compile_cache_sizes()
-        assert sizes[("fwd", 0)] == len(lengths)
+        assert sizes[("forward", 0)] == len(lengths)
 
 
 # ---------------------------------------------------------------------------
@@ -261,10 +257,10 @@ class TestEndToEnd:
             enc_layers_per_stage=1, lm_layers_per_stage=1, text_seq=8,
             fusion_slots=2, mean_enc_tokens=10, buckets=(8, 16))
         params = model.init_stage_params(jax.random.key(1))
-        fns = MultimodalStageFns(model, MultimodalStageOptions(
-            mb_rows=1, loss_scale=1.0 / 16))
+        fns = MultimodalStageFns(model, StageFnOptions(
+            mb_rows=1, seq_len=8, loss_scale=1.0 / 16))
         batch = multimodal_batch(model.cfg, 2, 1, seed=0, step=0)
-        programs = [MultimodalStageProgram(fns, s, params[s], batch)
+        programs = [ActorStageProgram(fns, s, params[s], {}, batch)
                     for s in range(model.cfg.num_stages)]
         acfg = ActorConfig(mode="hint", hint=HintKind.BF,
                            deadlock_timeout=120.0)
@@ -287,8 +283,8 @@ class TestEndToEnd:
         model, params, fns = tiny
         cfg = model.cfg
         batch = multimodal_batch(cfg, M, ROWS, seed=0, step=0)
-        prog = MultimodalStageProgram(
-            fns, cfg.fusion_stage, params[cfg.fusion_stage], batch)
+        prog = ActorStageProgram(
+            fns, cfg.fusion_stage, params[cfg.fusion_stage], {}, batch)
         h_enc = jax.numpy.zeros((ROWS, BUCKETS[0], cfg.d_enc))
         h_txt = jax.numpy.zeros((ROWS, cfg.text_seq, cfg.d_model))
         y = prog(Task(Kind.F, cfg.fusion_stage, 0),
@@ -299,3 +295,72 @@ class TestEndToEnd:
         assert set(dx) == {cfg.enc_stages - 1, cfg.text_stage}
         assert dx[cfg.enc_stages - 1].shape == h_enc.shape
         assert dx[cfg.text_stage].shape == h_txt.shape
+
+
+# ---------------------------------------------------------------------------
+# the chain's stage program on the DAG
+# ---------------------------------------------------------------------------
+class TestStageProgram:
+    def test_stage_modules_carry_stage_and_op(self, tiny):
+        """Every DAG stage's callables lower as ``jit_stage{s}_F``/``_B``,
+        and ``_dX``/``_W`` for split backward (no dX at a source stage)."""
+        import jax.numpy as jnp
+
+        from repro.pipeline.stagefn import zero_accumulators
+
+        model, params, fns = tiny
+        cfg = model.cfg
+        batch = multimodal_batch(cfg, M, ROWS, seed=0, step=0)
+        graph = cfg.stage_graph()
+        ys = {}
+        for s in graph.topological_order():
+            preds = graph.preds(s)
+            x = ({p: ys[p] for p in preds} if len(preds) > 1
+                 else ys[preds[0]] if preds else None)
+            bm = fns.microbatch(batch, 0, s)
+            ys[s], _ = fns.forward(s)(params[s], {}, x, bm)
+            args = (params[s], {}, x, jnp.zeros_like(ys[s]), bm)
+            acc, _ = zero_accumulators(params[s], {})
+            lowered = {
+                "F": [fns.forward(s).lower(params[s], {}, x, bm)],
+                "B": [fns.backward(s).lower(*args),
+                      fns.backward_into(s).lower(acc, *args)],
+                "W": [fns.weight_grad(s).lower(*args),
+                      fns.weight_grad_into(s).lower(acc, *args)],
+            }
+            if x is not None:
+                lowered["dX"] = [fns.backward_dx(s).lower(*args)]
+            assert ("dX" in lowered) == (s not in graph.sources())
+            for op, lows in lowered.items():
+                for low in lows:
+                    assert f"module @jit_stage{s}_{op} " in low.as_text()
+
+    def test_eager_step_matches_deterministic_in_bf16(self, tiny):
+        """A bf16 DAG step in eager mode folds M microbatches inside each
+        stage's B callable, and its loss and grads match the deterministic
+        microbatch-order fold within bf16 rounding."""
+        import dataclasses
+
+        import jax.numpy as jnp
+
+        from repro.multimodal import MultimodalModel
+
+        cfg = tiny[0].cfg
+        model = MultimodalModel(dataclasses.replace(
+            cfg, lm_cfg=dataclasses.replace(cfg.lm_cfg, dtype=jnp.bfloat16)))
+        params = model.init_stage_params(jax.random.key(0))
+        fns = MultimodalStageFns(model, StageFnOptions(
+            mb_rows=ROWS, seq_len=SEQ, loss_scale=1.0 / (M * ROWS * SEQ)))
+        eager = run_step(model, params, fns, deterministic=False)
+        det = run_step(model, params, fns, deterministic=True)
+        for pe, pd in zip(eager, det):
+            assert pe.folded_in_callable == M
+            assert pd.folded_in_callable == 0
+            assert abs(pe.loss_sum - pd.loss_sum) <= 1e-6 * abs(pd.loss_sum)
+            for ge, gd in zip(jax.tree.leaves(pe.d_stage),
+                              jax.tree.leaves(pd.d_stage)):
+                assert ge.dtype == gd.dtype == jnp.bfloat16
+                gd32 = gd.astype(jnp.float32)
+                scale = float(jnp.max(jnp.abs(gd32))) + 1e-30
+                err = float(jnp.max(jnp.abs(ge.astype(jnp.float32) - gd32)))
+                assert err <= 2 ** -6 * scale

@@ -149,12 +149,14 @@ def test_threaded_bfw_matches_fused_run():
 def test_fused_program_rejects_w_task():
     import numpy as np
 
-    from repro.pipeline.stagefn import ActorStageProgram
+    from repro.pipeline.stagefn import ActorStageProgram, microbatch
 
     prog = ActorStageProgram.__new__(ActorStageProgram)
     prog.split_backward = False
+    prog.stage = 0
     prog.batch = {"tokens": np.zeros((2, 4), np.int32)}
-    prog.fns = type("F", (), {"opts": type("O", (), {"mb_rows": 1})()})()
+    prog.fns = type("F", (), {"microbatch": staticmethod(
+        lambda batch, mb, stage: microbatch(batch, mb, 1))})()
     with pytest.raises(ValueError, match="split_backward=True"):
         ActorStageProgram.__call__(prog, Task(Kind.W, 0, 0), None)
 
